@@ -78,6 +78,8 @@ def main() -> None:
                          "per-suite registry snapshot) at PATH")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = []
     report = {}

@@ -71,10 +71,10 @@ def _live(j: jax.Array, bs: int, qpos: jax.Array, window: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _gqa_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                m_ref, l_ref, acc_ref, *, bs: int, mb: int, window: int,
-                softcap: float, scale: float):
+                m_ref, l_ref, acc_ref, *, bs: int, mb: int, kvh: int,
+                window: int, softcap: float, scale: float):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -86,24 +86,26 @@ def _gqa_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(_live(j, bs, qpos, window))
     def _block():
-        q = q_ref[0, 0].astype(jnp.float32)               # (G, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)            # (bs, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap > 0:
-            s = softcap * jnp.tanh(s / softcap)
-        k_pos = j * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (q.shape[0], bs), 1)
-        mask = k_pos <= qpos
-        if window > 0:
-            mask &= (qpos - k_pos) < window
-        v = v_ref[0, :, 0].astype(jnp.float32)            # (bs, d)
-        _online_softmax_step(s, mask, v, m_ref, l_ref, acc_ref)
+        for h in range(kvh):                              # static head loop
+            q = q_ref[0, h].astype(jnp.float32)           # (G, d)
+            k = k_ref[0, :, h].astype(jnp.float32)        # (bs, d)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale
+            if softcap > 0:
+                s = softcap * jnp.tanh(s / softcap)
+            k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = k_pos <= qpos
+            if window > 0:
+                mask &= (qpos - k_pos) < window
+            v = v_ref[0, :, h].astype(jnp.float32)        # (bs, d)
+            _online_softmax_step(s, mask, v, m_ref.at[h], l_ref.at[h],
+                                 acc_ref.at[h])
 
     @pl.when(j == mb - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l[..., None]).astype(o_ref.dtype)
 
 
 def paged_decode_gqa(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -113,40 +115,38 @@ def paged_decode_gqa(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     """q (B, KVH, G, d); k/v pools (nb, bs, KVH, d); tables (B, mb) int32;
     seq_lens (B,) int32 -> out (B, KVH, G, d) in q.dtype.
 
-    grid = (B, KVH, mb); each program streams ONE (bs, d) KV block of one
-    kv-head straight out of the pool (no per-sequence gather); the G
-    group-queries of that kv-head are packed as MXU rows (head-group
-    packing).
+    grid = (B, mb); each program streams ONE whole (bs, KVH, d) pool block
+    (its last two dims are full dims, as Mosaic's tiling requires) and
+    loops over the kv-heads in VMEM; the G group-queries of a kv-head are
+    packed as MXU rows (head-group packing).
     """
     B, KVH, G, d = q.shape
     bs = k_pool.shape[1]
     mb = block_tables.shape[1]
-    kern = functools.partial(_gqa_kernel, bs=bs, mb=mb, window=window,
-                             softcap=softcap, scale=d ** -0.5)
+    kern = functools.partial(_gqa_kernel, bs=bs, mb=mb, kvh=KVH,
+                             window=window, softcap=softcap, scale=d ** -0.5)
 
-    def blk(b, h, j, tables, lens):
+    def blk(b, j, tables, lens):
         # clamp dead walks onto the live range so their (elided) DMA
         # re-targets a resident block: above the tail, and — on windowed
         # layers — below the first in-window block
         jc = jnp.minimum(j, lens[b] // bs)
         if window > 0:
             jc = jnp.maximum(jc, jnp.maximum(lens[b] - window + 1, 0) // bs)
-        return (tables[b, jc], 0, h, 0)
+        return (tables[b, jc], 0, 0, 0)
 
+    q_spec = pl.BlockSpec((1, KVH, G, d), lambda b, j, t, L: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KVH, mb),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, d), lambda b, h, j, t, L: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d), blk),
-            pl.BlockSpec((1, bs, 1, d), blk),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, d),
-                               lambda b, h, j, t, L: (b, h, 0, 0)),
+        grid=(B, mb),
+        in_specs=[q_spec,
+                  pl.BlockSpec((1, bs, KVH, d), blk),
+                  pl.BlockSpec((1, bs, KVH, d), blk)],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, d), jnp.float32),
+            pltpu.VMEM((KVH, G), jnp.float32),
+            pltpu.VMEM((KVH, G), jnp.float32),
+            pltpu.VMEM((KVH, G, d), jnp.float32),
         ],
     )
     return pl.pallas_call(
@@ -244,61 +244,134 @@ def paged_decode_mla(q_lat: jax.Array, q_rope: jax.Array, c_pool: jax.Array,
 # DSA lightning-indexer scores over the paged k_idx pool
 # ---------------------------------------------------------------------------
 
-def _indexer_kernel(tables_ref, lens_ref, q_ref, w_ref, k_ref, o_ref, *,
-                    bs: int, scale: float):
+LANES = 128
+
+
+def blocks_per_step(bs: int) -> int:
+    """Pool blocks one indexer grid step scores: enough to fill a 128-lane
+    tile, since the minor dim of a TPU output block must be a multiple of
+    128 unless it spans the whole array."""
+    return LANES // bs if bs < LANES and LANES % bs == 0 else 1
+
+
+# query rows (tokens x indexer heads) one indexer tile keeps in VMEM
+INDEXER_TILE_ROWS = 2048
+
+
+def query_tile(S: int, rows_per_token: int, max_rows: int) -> int:
+    """Tokens per query tile: the whole span when its rows fit
+    ``max_rows``; otherwise tiles of equal size, a multiple of 8 tokens
+    (sublane-aligned), each at most ``max_rows`` rows where 8 tokens fit.
+    The span is padded to whole tiles (``pad_tokens``)."""
+    if S * rows_per_token <= max_rows:
+        return S
+    most = max(8, max_rows // rows_per_token // 8 * 8)
+    return pl.cdiv(pl.cdiv(S, pl.cdiv(S, most)), 8) * 8
+
+
+def pad_tokens(x: jax.Array, S: int, tq: int, axis: int = 1) -> jax.Array:
+    """Zero-pad the packed token axis of ``x`` (S tokens of equal row
+    count) to whole tiles of ``tq`` tokens.  Padded queries sit past the
+    span; their outputs are sliced off by the caller."""
+    rows = x.shape[axis] // S
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, -S % tq * rows)
+    return jnp.pad(x, pad)
+
+
+def _indexer_kernel(tables_ref, starts_ref, q_ref, w_ref, *refs, bs: int,
+                    nsub: int, tq: int, hi: int, scale: float):
+    k_refs, o_ref = refs[:nsub], refs[nsub]
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    qpos = lens_ref[b]
-    live = _live(j, bs, qpos, 0)
+    j = pl.program_id(2)
+    last = starts_ref[b] + (pl.program_id(1) + 1) * tq - 1   # tile's last q
+    live = j * nsub * bs <= last
 
     @pl.when(live)
     def _block():
-        q = q_ref[0].astype(jnp.float32)                  # (Hi, Di)
-        w = w_ref[0].astype(jnp.float32)                  # (Hi,)
-        k = k_ref[0].astype(jnp.float32)                  # (bs, Di)
-        dots = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+        q = q_ref[0].astype(jnp.float32)                  # (tq*Hi, Di)
+        w = w_ref[0].astype(jnp.float32)                  # (tq*Hi, 1)
+        k = jnp.concatenate([r[0].astype(jnp.float32) for r in k_refs],
+                            axis=0)                       # (nsub*bs, Di)
+        dots = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                    preferred_element_type=jnp.float32)
-        s = jax.lax.dot_general(jax.nn.relu(dots) * scale, w[:, None],
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        o_ref[0] = s[:, 0]
+        dots = jax.nn.relu(dots) * scale * w              # (tq*Hi, nsub*bs)
+        s = dots.reshape(tq, hi, nsub * bs).sum(axis=1)   # (tq, nsub*bs)
+        k_blk = (j * nsub * bs + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)) // bs
+        o_ref[0] = jnp.where(k_blk <= last // bs, s, NEG_INF)
 
     @pl.when(jnp.logical_not(live))
     def _dead():
-        o_ref[0] = jnp.full((bs,), NEG_INF, jnp.float32)
+        o_ref[0] = jnp.full((tq, nsub * bs), NEG_INF, jnp.float32)
+
+
+def indexer_span_scores(q_idx: jax.Array, w_head: jax.Array,
+                        k_pool: jax.Array, block_tables: jax.Array,
+                        starts: jax.Array, *, heads: int,
+                        interpret: bool = False) -> jax.Array:
+    """DSA indexer scores of an S-token span against the k_idx pool.
+
+    q_idx (B, S*Hi, Di) packed (row i*Hi + h = head h of token i); w_head
+    (B, S*Hi) softmaxed; k_pool (nb, bs, Di); starts (B,) -> scores
+    (B, S, mb*bs) fp32 in view coordinates (index == absolute position).
+
+    grid = (B, query tiles, block groups): each step scores
+    ``blocks_per_step(bs)`` pool blocks into one lane-dense tile.  Blocks
+    past the tile's last query position emit NEG_INF (the selector's
+    causal mask excludes them anyway).
+    """
+    B, SHi, Di = q_idx.shape
+    S = SHi // heads
+    bs = k_pool.shape[1]
+    mb = block_tables.shape[1]
+    nsub = blocks_per_step(bs)
+    steps = pl.cdiv(mb, nsub)
+    tq = query_tile(S, heads, INDEXER_TILE_ROWS)
+    kern = functools.partial(_indexer_kernel, bs=bs, nsub=nsub, tq=tq,
+                             hi=heads, scale=Di ** -0.5)
+
+    def k_spec(i):
+        def idx(b, qt, j, tables, starts):
+            # clamp onto the tile's last live block, and inside the table
+            # (group walks and padded tiles can run past its end)
+            last = jnp.minimum((starts[b] + (qt + 1) * tq - 1) // bs, mb - 1)
+            return (tables[b, jnp.minimum(j * nsub + i, last)], 0, 0)
+        return pl.BlockSpec((1, bs, Di), idx)
+
+    q_idx = pad_tokens(q_idx, S, tq)
+    w_head = pad_tokens(w_head, S, tq)
+    n_tiles = q_idx.shape[1] // (tq * heads)
+    q_rows = lambda b, qt, j, t, st: (b, qt, 0)           # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, n_tiles, steps),
+        in_specs=[pl.BlockSpec((1, tq * heads, Di), q_rows),
+                  pl.BlockSpec((1, tq * heads, 1), q_rows),
+                  *[k_spec(i) for i in range(nsub)]],
+        out_specs=pl.BlockSpec((1, tq, nsub * bs),
+                               lambda b, qt, j, t, st: (b, qt, j)),
+    )
+    out = pl.pallas_call(
+        kern, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, n_tiles * tq, steps * nsub * bs),
+                                       jnp.float32),
+        interpret=interpret,
+    )(block_tables, starts, q_idx, w_head[..., None], *[k_pool] * nsub)
+    return out[:, :S, :mb * bs]
 
 
 def paged_indexer_scores_kernel(q_idx: jax.Array, w_head: jax.Array,
                                 k_pool: jax.Array, block_tables: jax.Array,
                                 seq_lens: jax.Array, *,
                                 interpret: bool = False) -> jax.Array:
-    """DSA decode indexer scores against the k_idx pool, in place.
+    """DSA decode indexer scores against the k_idx pool, in place: the
+    one-token span at ``seq_lens``.
 
     q_idx (B, Hi, Di); w_head (B, Hi) (softmaxed); k_pool (nb, bs, Di) ->
     scores (B, mb*bs) fp32 in VIEW coordinates (index == absolute
     position).  Dead blocks emit NEG_INF; the selector masks them anyway.
     """
-    B, Hi, Di = q_idx.shape
-    bs = k_pool.shape[1]
-    mb = block_tables.shape[1]
-    kern = functools.partial(_indexer_kernel, bs=bs, scale=Di ** -0.5)
-
-    def blk(b, j, tables, lens):
-        jc = jnp.minimum(j, lens[b] // bs)
-        return (tables[b, jc], 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, mb),
-        in_specs=[
-            pl.BlockSpec((1, Hi, Di), lambda b, j, t, lens: (b, 0, 0)),
-            pl.BlockSpec((1, Hi), lambda b, j, t, lens: (b, 0)),
-            pl.BlockSpec((1, bs, Di), blk),
-        ],
-        out_specs=pl.BlockSpec((1, bs), lambda b, j, t, lens: (b, j)),
-    )
-    return pl.pallas_call(
-        kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, mb * bs), jnp.float32),
-        interpret=interpret,
-    )(block_tables, seq_lens, q_idx, w_head, k_pool)
+    return indexer_span_scores(q_idx, w_head, k_pool, block_tables,
+                               seq_lens, heads=q_idx.shape[1],
+                               interpret=interpret)[:, 0]

@@ -7,7 +7,8 @@ falling back to the decode env):
 
 * ``"pallas"`` — read KV blocks in place (O(live tokens) traffic):
     - TPU backend: the compiled Pallas kernels (``kernel.py`` for decode,
-      ``prefill.py`` for spans);
+      ``prefill.py`` for spans; one indexer kernel, in ``kernel.py``,
+      scores decode and spans alike);
     - CPU with ``JAX_PALLAS_INTERPRET=1``: the same kernels in interpret
       mode (CI parity coverage of the kernel code itself);
     - CPU otherwise: an XLA twin — a ``fori_loop`` over live blocks whose
@@ -475,7 +476,7 @@ def _indexer_prefill_jit(q_idx, w_head, k_pool, block_tables, starts, *,
     if eff == "blocked":
         return _blocked_indexer_prefill(q_idx, w_head, k_pool, block_tables,
                                         starts)
-    return _p.paged_prefill_indexer(
+    return _k.indexer_span_scores(
         q_idx.reshape(B, S * Hi, Di), w_head.reshape(B, S * Hi),
         k_pool, block_tables, starts, heads=Hi,
         interpret=eff == "pallas_interpret")

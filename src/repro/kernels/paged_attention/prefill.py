@@ -43,7 +43,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.paged_attention.kernel import (NEG_INF,
-                                                  _online_softmax_step)
+                                                  _online_softmax_step,
+                                                  pad_tokens, query_tile)
+
+# query rows one tile keeps resident (with its f32 accumulator) in VMEM
+MAX_TILE_ROWS = 512
 
 
 def _span_live(j: jax.Array, bs: int, start: jax.Array, S: int,
@@ -70,8 +74,9 @@ def _span_clamp(j: jax.Array, bs: int, start: jax.Array, S: int,
 # ---------------------------------------------------------------------------
 
 def _gqa_prefill_kernel(tables_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,
-                        m_ref, l_ref, acc_ref, *, bs: int, mb: int, S: int,
-                        G: int, window: int, softcap: float, scale: float):
+                        m_ref, l_ref, acc_ref, *, bs: int, mb: int, tq: int,
+                        G: int, kvh: int, window: int, softcap: float,
+                        scale: float):
     b = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -81,32 +86,35 @@ def _gqa_prefill_kernel(tables_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    start = starts_ref[b]
+    start = starts_ref[b] + pl.program_id(1) * tq        # tile's first query
 
-    @pl.when(_span_live(j, bs, start, S, window))
+    @pl.when(_span_live(j, bs, start, tq, window))
     def _block():
-        q = q_ref[0, 0].astype(jnp.float32)               # (S*G, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)            # (bs, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap > 0:
-            s = softcap * jnp.tanh(s / softcap)
-        # row r of the packed (S*G, ·) block is query i = r // G at
+        # row r of the packed (tq*G, ·) tile is query i = r // G at
         # absolute position start + i; keys live at j*bs + t
         q_pos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (S * G, bs), 0) // G
+            jnp.int32, (tq * G, bs), 0) // G
         k_pos = j * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (S * G, bs), 1)
+            jnp.int32, (tq * G, bs), 1)
         mask = k_pos <= q_pos
         if window > 0:
             mask &= (q_pos - k_pos) < window
-        v = v_ref[0, :, 0].astype(jnp.float32)            # (bs, d)
-        _online_softmax_step(s, mask, v, m_ref, l_ref, acc_ref)
+        for h in range(kvh):                              # static head loop
+            q = q_ref[0, h].astype(jnp.float32)           # (tq*G, d)
+            k = k_ref[0, :, h].astype(jnp.float32)        # (bs, d)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale
+            if softcap > 0:
+                s = softcap * jnp.tanh(s / softcap)
+            v = v_ref[0, :, h].astype(jnp.float32)        # (bs, d)
+            _online_softmax_step(s, mask, v, m_ref.at[h], l_ref.at[h],
+                                 acc_ref.at[h])
 
     @pl.when(j == mb - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l[..., None]).astype(o_ref.dtype)
 
 
 def paged_prefill_gqa(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -118,8 +126,9 @@ def paged_prefill_gqa(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 
     ``groups`` (= G = H // KVH) recovers S from the packed axis — row
     ``i*G + g`` is group-query ``g`` of span token ``i`` (head-group
-    packing, MXU rows).  grid = (B, KVH, mb): each program streams ONE
-    (bs, d) KV block of one kv-head against the whole resident span.
+    packing, MXU rows).  grid = (B, query tiles, mb): each program streams
+    ONE whole (bs, KVH, d) pool block against a resident query tile and
+    loops over the kv-heads in VMEM.
     """
     B, KVH, SG, d = q.shape
     bs = k_pool.shape[1]
@@ -127,35 +136,36 @@ def paged_prefill_gqa(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     G = groups
     assert SG % G == 0, (SG, G)
     S = SG // G
-    kern = functools.partial(_gqa_prefill_kernel, bs=bs, mb=mb, S=S, G=G,
-                             window=window, softcap=softcap,
+    tq = query_tile(S, G, MAX_TILE_ROWS)
+    kern = functools.partial(_gqa_prefill_kernel, bs=bs, mb=mb, tq=tq, G=G,
+                             kvh=KVH, window=window, softcap=softcap,
                              scale=d ** -0.5)
 
-    def blk(b, h, j, tables, starts):
-        jc = _span_clamp(j, bs, starts[b], S, window)
-        return (tables[b, jc], 0, h, 0)
+    def blk(b, qt, j, tables, starts):
+        jc = _span_clamp(j, bs, starts[b] + qt * tq, tq, window)
+        return (tables[b, jc], 0, 0, 0)
 
+    qp = pad_tokens(q, S, tq, axis=2)
+    q_spec = pl.BlockSpec((1, KVH, tq * G, d),
+                          lambda b, qt, j, t, st: (b, 0, qt, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KVH, mb),
-        in_specs=[
-            pl.BlockSpec((1, 1, SG, d), lambda b, h, j, t, st: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d), blk),
-            pl.BlockSpec((1, bs, 1, d), blk),
-        ],
-        out_specs=pl.BlockSpec((1, 1, SG, d),
-                               lambda b, h, j, t, st: (b, h, 0, 0)),
+        grid=(B, qp.shape[2] // (tq * G), mb),
+        in_specs=[q_spec,
+                  pl.BlockSpec((1, bs, KVH, d), blk),
+                  pl.BlockSpec((1, bs, KVH, d), blk)],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((SG,), jnp.float32),
-            pltpu.VMEM((SG,), jnp.float32),
-            pltpu.VMEM((SG, d), jnp.float32),
+            pltpu.VMEM((KVH, tq * G), jnp.float32),
+            pltpu.VMEM((KVH, tq * G), jnp.float32),
+            pltpu.VMEM((KVH, tq * G, d), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KVH, SG, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
         interpret=interpret,
-    )(block_tables, starts, q, k_pool, v_pool)
+    )(block_tables, starts, qp, k_pool, v_pool)[:, :, :SG]
 
 
 # ---------------------------------------------------------------------------
@@ -164,9 +174,9 @@ def paged_prefill_gqa(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 
 def _mla_prefill_kernel(tables_ref, starts_ref, ql_ref, qr_ref, c_ref,
                         kr_ref, o_ref, m_ref, l_ref, acc_ref, *, bs: int,
-                        mb: int, S: int, H: int, scale: float):
+                        mb: int, tq: int, H: int, scale: float):
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -174,12 +184,12 @@ def _mla_prefill_kernel(tables_ref, starts_ref, ql_ref, qr_ref, c_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    start = starts_ref[b]
+    start = starts_ref[b] + pl.program_id(1) * tq        # tile's first query
 
-    @pl.when(_span_live(j, bs, start, S, 0))
+    @pl.when(_span_live(j, bs, start, tq, 0))
     def _block():
-        ql = ql_ref[0].astype(jnp.float32)                # (S*H, lora)
-        qr = qr_ref[0].astype(jnp.float32)                # (S*H, rope)
+        ql = ql_ref[0].astype(jnp.float32)                # (tq*H, lora)
+        qr = qr_ref[0].astype(jnp.float32)                # (tq*H, rope)
         c = c_ref[0].astype(jnp.float32)                  # (bs, lora)
         kr = kr_ref[0].astype(jnp.float32)                # (bs, rope)
         dn = (((1,), (1,)), ((), ()))
@@ -187,11 +197,11 @@ def _mla_prefill_kernel(tables_ref, starts_ref, ql_ref, qr_ref, c_ref,
                                  preferred_element_type=jnp.float32)
              + jax.lax.dot_general(qr, kr, dn,
                                    preferred_element_type=jnp.float32))
-        s = s * scale                                     # (S*H, bs)
+        s = s * scale                                     # (tq*H, bs)
         q_pos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (S * H, bs), 0) // H
+            jnp.int32, (tq * H, bs), 0) // H
         k_pos = j * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (S * H, bs), 1)
+            jnp.int32, (tq * H, bs), 1)
         _online_softmax_step(s, k_pos <= q_pos, c, m_ref, l_ref, acc_ref)
 
     @pl.when(j == mb - 1)
@@ -209,105 +219,45 @@ def paged_prefill_mla(q_lat: jax.Array, q_rope: jax.Array,
 
     q_lat (B, S*H, lora) packed (row i*H + h = head h of span token i);
     q_rope (B, S*H, rope); c/kr pools (nb, bs, ·) -> out_lat (B, S*H, lora)
-    fp32 (``probs · c``; caller applies W^UV, W^O).  grid = (B, mb).
+    fp32 (``probs · c``; caller applies W^UV, W^O).  grid = (B, query
+    tiles, mb): VMEM holds one tile of whole tokens and its accumulator.
     """
     B, SH, L = q_lat.shape
+    R = q_rope.shape[-1]
     S = SH // heads
     bs = c_pool.shape[1]
     mb = block_tables.shape[1]
-    kern = functools.partial(_mla_prefill_kernel, bs=bs, mb=mb, S=S,
+    tq = query_tile(S, heads, MAX_TILE_ROWS)
+    rows = tq * heads
+    kern = functools.partial(_mla_prefill_kernel, bs=bs, mb=mb, tq=tq,
                              H=heads, scale=scale)
 
-    def blk(b, j, tables, starts):
-        jc = _span_clamp(j, bs, starts[b], S, 0)
+    def blk(b, qt, j, tables, starts):
+        jc = _span_clamp(j, bs, starts[b] + qt * tq, tq, 0)
         return (tables[b, jc], 0, 0)
 
+    q_lat = pad_tokens(q_lat, S, tq)
+    q_rope = pad_tokens(q_rope, S, tq)
+    q_rows = lambda b, qt, j, t, st: (b, qt, 0)           # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, mb),
+        grid=(B, q_lat.shape[1] // rows, mb),
         in_specs=[
-            pl.BlockSpec((1, SH, L), lambda b, j, t, st: (b, 0, 0)),
-            pl.BlockSpec((1, SH, q_rope.shape[-1]),
-                         lambda b, j, t, st: (b, 0, 0)),
+            pl.BlockSpec((1, rows, L), q_rows),
+            pl.BlockSpec((1, rows, R), q_rows),
             pl.BlockSpec((1, bs, L), blk),
-            pl.BlockSpec((1, bs, kr_pool.shape[-1]), blk),
+            pl.BlockSpec((1, bs, R), blk),
         ],
-        out_specs=pl.BlockSpec((1, SH, L), lambda b, j, t, st: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, rows, L), q_rows),
         scratch_shapes=[
-            pltpu.VMEM((SH,), jnp.float32),
-            pltpu.VMEM((SH,), jnp.float32),
-            pltpu.VMEM((SH, L), jnp.float32),
+            pltpu.VMEM((rows,), jnp.float32),
+            pltpu.VMEM((rows,), jnp.float32),
+            pltpu.VMEM((rows, L), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, SH, L), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(q_lat.shape, jnp.float32),
         interpret=interpret,
-    )(block_tables, starts, q_lat, q_rope, c_pool, kr_pool)
+    )(block_tables, starts, q_lat, q_rope, c_pool, kr_pool)[:, :SH]
 
-
-# ---------------------------------------------------------------------------
-# DSA lightning-indexer span scores over the paged k_idx pool
-# ---------------------------------------------------------------------------
-
-def _indexer_prefill_kernel(tables_ref, starts_ref, q_ref, w_ref, k_ref,
-                            o_ref, *, bs: int, S: int, Hi: int,
-                            scale: float):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    start = starts_ref[b]
-    live = _span_live(j, bs, start, S, 0)
-
-    @pl.when(live)
-    def _block():
-        q = q_ref[0].astype(jnp.float32)                  # (S*Hi, Di)
-        w = w_ref[0].astype(jnp.float32)                  # (S*Hi,)
-        k = k_ref[0].astype(jnp.float32)                  # (bs, Di)
-        dots = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        dots = jax.nn.relu(dots) * scale                  # (S*Hi, bs)
-        s = (dots * w[:, None]).reshape(S, Hi, bs).sum(axis=1)
-        o_ref[0] = s                                      # (S, bs)
-
-    @pl.when(jnp.logical_not(live))
-    def _dead():
-        o_ref[0] = jnp.full((S, bs), NEG_INF, jnp.float32)
-
-
-def paged_prefill_indexer(q_idx: jax.Array, w_head: jax.Array,
-                          k_pool: jax.Array, block_tables: jax.Array,
-                          starts: jax.Array, *, heads: int,
-                          interpret: bool = False) -> jax.Array:
-    """DSA span indexer scores against the k_idx pool, in place.
-
-    q_idx (B, S*Hi, Di) packed; w_head (B, S*Hi) softmaxed weights flat;
-    k_pool (nb, bs, Di) -> scores (B, S, mb*bs) fp32 in view coordinates.
-    Dead blocks emit NEG_INF; the selector's causal mask excludes them
-    anyway.
-    """
-    B, SHi, Di = q_idx.shape
-    S = SHi // heads
-    bs = k_pool.shape[1]
-    mb = block_tables.shape[1]
-    kern = functools.partial(_indexer_prefill_kernel, bs=bs, S=S, Hi=heads,
-                             scale=Di ** -0.5)
-
-    def blk(b, j, tables, starts):
-        jc = _span_clamp(j, bs, starts[b], S, 0)
-        return (tables[b, jc], 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, mb),
-        in_specs=[
-            pl.BlockSpec((1, SHi, Di), lambda b, j, t, st: (b, 0, 0)),
-            pl.BlockSpec((1, SHi), lambda b, j, t, st: (b, 0)),
-            pl.BlockSpec((1, bs, Di), blk),
-        ],
-        out_specs=pl.BlockSpec((1, S, bs), lambda b, j, t, st: (b, 0, j)),
-    )
-    return pl.pallas_call(
-        kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, mb * bs), jnp.float32),
-        interpret=interpret,
-    )(block_tables, starts, q_idx, w_head, k_pool)

@@ -10,16 +10,7 @@ from __future__ import annotations
 import math
 
 import jax
-from jax.sharding import Mesh
-
-try:   # AxisType landed after jax 0.4.x; Auto is the only behavior before
-    from jax.sharding import AxisType
-
-    def auto_axis_types(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:   # pragma: no cover - older jax
-    def auto_axis_types(n: int) -> dict:
-        return {}
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -33,9 +24,10 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n} (dryrun.py "
             f"does this automatically)")
     return jax.make_mesh(shape, axes, devices=devices[:n],
-                         **auto_axis_types(len(shape)))
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_host_mesh() -> Mesh:
     """1-device mesh for smoke tests / examples on CPU."""
-    return jax.make_mesh((1, 1), ("data", "model"), **auto_axis_types(2))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

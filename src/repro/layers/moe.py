@@ -29,13 +29,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.sharding.rules import Builder
 
-if hasattr(jax, "shard_map"):            # jax >= 0.6: top-level API
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:                                    # older jax: experimental API
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
 
 def build_moe(b: Builder, cfg: ModelConfig):
     D, E, F = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
@@ -177,9 +170,9 @@ def apply_moe(params, x: jax.Array, cfg: ModelConfig, *,
                     P("model", None, None), P("model", None, None),
                     P("model", None, None))
         out_specs = (P(batch_axes if batch_axes else None, None), P())
-        y2d, aux = _shard_map(
+        y2d, aux = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            **_SHARD_MAP_KW,
+            check_vma=False,
         )(x2d, params["router"], params["w_gate"], params["w_up"],
           params["w_down"])
     else:

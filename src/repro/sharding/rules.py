@@ -213,8 +213,10 @@ class Builder:
             if scale is None:
                 fan_in = shape[0] if len(shape) >= 2 else max(shape[-1], 1)
                 scale = 1.0 / math.sqrt(fan_in)
-            arr = (jax.random.normal(self._next_key(), shape) * scale
-                   ).astype(self.dtype)
+            # drawn in the target dtype: a bf16 model never has a float32
+            # copy of a weight resident, even transiently
+            arr = jax.random.normal(self._next_key(), shape,
+                                    self.dtype) * scale
         elif init == "arange_log":   # mamba A_log init
             n = shape[-1]
             base = jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32))

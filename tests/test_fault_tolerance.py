@@ -19,8 +19,7 @@ Covers the acceptance criteria:
   * byte-parity: under an injected per-request fault, SURVIVING requests
     produce byte-identical greedy outputs vs the fault-free oracle on
     all four families (GQA / DSA / MLA / hybrid);
-  * property test (hypothesis when installed, the fixed-seed fallback
-    otherwise): refcount conservation, free-list integrity, and
+  * property test (hypothesis): refcount conservation, free-list integrity, and
     no-double-free under random interleavings of submit / cancel /
     deadline-expiry / shed-pressure / push_weights / step;
   * ``env_spec`` tests (CI fault matrix + ``make fault-smoke``): the
@@ -35,7 +34,7 @@ import jax
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from repro.configs import get_smoke_config
 from repro.faults import FaultInjector, InjectedFault
 from repro.models import get_model

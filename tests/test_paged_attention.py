@@ -272,6 +272,4 @@ def test_decode_compiles_once_across_admit_retire():
     _, eng = _serve(cfg, params, None, [5, 17, 9, 33, 1, 26],
                     [3, 9, 5, 12, 1, 7], **_KW)
     assert any(s > 0 for s in eng.stats["admit_steps"])   # churn happened
-    if not hasattr(eng._decode, "_cache_size"):
-        pytest.skip("jax too old for jit cache introspection")
     assert eng._decode._cache_size() == 1

@@ -8,10 +8,9 @@ Covers the acceptance criteria:
   * chunked-vs-whole-suffix greedy byte-parity through the engine for all
     four families (incl. radix-cached suffixes starting mid-block), and
     in-place-vs-ref prefill byte-parity under chunking;
-  * the scan-invariant pool: a decode step on a SCANNED (non-first_k_dense)
-    config reuses the donated pool buffer in place — its compiled temp
-    allocation stays far below the pool size (the old stacked-xs/ys layout
-    round-tripped the whole pool through scan outputs every step);
+  * the scan-invariant pool: a scanned decode step computes the same
+    math as the contiguous cache (its donated-pool aliasing is checked on
+    the v5e compile in tests/test_tpu_compile.py);
   * stats: ``prefill_gather_bytes_saved`` accounts the traffic the
     in-place span path avoided.
 """
@@ -26,12 +25,11 @@ from repro.kernels.paged_attention import ref as pref
 from repro.kernels.paged_attention.ops import (_blocked_gqa_prefill,
                                                _blocked_indexer_prefill,
                                                _blocked_mla_prefill)
+from repro.kernels.paged_attention.kernel import indexer_span_scores
 from repro.kernels.paged_attention.prefill import (paged_prefill_gqa,
-                                                   paged_prefill_indexer,
                                                    paged_prefill_mla)
 from repro.models import get_model
 from repro.serving import ContinuousEngine, Request
-from repro.utils import tree_bytes
 
 
 def _pool_setup(rng, B, mb, bs, feat):
@@ -107,7 +105,7 @@ def test_indexer_prefill_matches_on_live_positions():
     s_ref = np.asarray(pref.paged_indexer_prefill_reference(
         qi, w, kp, tables, starts))
     s_b = np.asarray(_blocked_indexer_prefill(qi, w, kp, tables, starts))
-    s_k = np.asarray(paged_prefill_indexer(
+    s_k = np.asarray(indexer_span_scores(
         qi.reshape(B, S * Hi, Di), w.reshape(B, S * Hi), kp, tables,
         starts, heads=Hi, interpret=True))
     # the selector only reads positions <= each query's position; the
@@ -123,6 +121,65 @@ def test_indexer_prefill_matches_on_live_positions():
     dead_block = (np.arange(mb * bs)[None] // bs) \
         > ((np.asarray(starts) + S - 1)[:, None] // bs)
     assert (s_k[dead_block[:, None, :].repeat(S, 1)] <= -1e29).all()
+
+
+@pytest.mark.parametrize("S", [16, 13], ids=["whole_tiles", "padded"])
+@pytest.mark.parametrize("family", ["gqa", "mla", "indexer"])
+def test_prefill_query_tiles_match_gather_ref(family, S, monkeypatch):
+    """A span longer than one query tile: the kernels walk the block table
+    once per tile, each tile with its own causal range; a span that is not
+    a whole number of tiles is padded to one."""
+    from repro.kernels.paged_attention import kernel as pk
+    from repro.kernels.paged_attention import prefill as pp
+    monkeypatch.setattr(pp, "MAX_TILE_ROWS", 16)
+    monkeypatch.setattr(pk, "INDEXER_TILE_ROWS", 16)
+    rng = np.random.default_rng(3)
+    B, bs, mb = 5, 8, 6
+    # the last span ends at the table's end: its padding runs past it
+    starts = _ragged_starts(B, mb, bs, S).at[-1].set(mb * bs - S)
+    if family == "gqa":
+        KVH, G, d = 2, 2, 16        # 8-token tiles
+        kp, tables = _pool_setup(rng, B, mb, bs, (KVH, d))
+        vp, _ = _pool_setup(rng, B, mb, bs, (KVH, d))
+        q = jnp.asarray(rng.standard_normal((B, S, KVH * G, d)), jnp.float32)
+        want = pref.paged_gqa_prefill_reference(q, kp, vp, tables, starts)
+        qp = q.reshape(B, S, KVH, G, d).transpose(0, 2, 1, 3, 4) \
+            .reshape(B, KVH, S * G, d)
+        got = paged_prefill_gqa(qp, kp, vp, tables, starts, groups=G,
+                                interpret=True)
+        got = got.reshape(B, KVH, S, G, d).transpose(0, 2, 1, 3, 4) \
+            .reshape(B, S, KVH * G, d)
+        live = True
+    elif family == "mla":
+        H, L, R = 2, 16, 8          # 8-token tiles
+        cp, tables = _pool_setup(rng, B, mb, bs, (L,))
+        krp, _ = _pool_setup(rng, B, mb, bs, (R,))
+        ql = jnp.asarray(rng.standard_normal((B, S, H, L)), jnp.float32)
+        qr = jnp.asarray(rng.standard_normal((B, S, H, R)), jnp.float32)
+        want = pref.paged_mla_prefill_reference(ql, qr, cp, krp, tables,
+                                                starts, scale=0.17)
+        got = paged_prefill_mla(
+            ql.reshape(B, S * H, L), qr.reshape(B, S * H, R), cp, krp,
+            tables, starts, heads=H, scale=0.17,
+            interpret=True).reshape(B, S, H, L)
+        live = True
+    else:
+        Hi, Di = 2, 16              # 8-token tiles
+        kp, tables = _pool_setup(rng, B, mb, bs, (Di,))
+        qi = jnp.asarray(rng.standard_normal((B, S, Hi, Di)), jnp.float32)
+        w = jax.nn.softmax(jnp.asarray(rng.standard_normal((B, S, Hi)),
+                                       jnp.float32), -1)
+        want = pref.paged_indexer_prefill_reference(qi, w, kp, tables,
+                                                    starts)
+        got = indexer_span_scores(qi.reshape(B, S * Hi, Di),
+                                  w.reshape(B, S * Hi), kp, tables,
+                                  starts, heads=Hi, interpret=True)
+        qpos = np.asarray(starts)[:, None] + np.arange(S)[None]
+        live = np.arange(mb * bs)[None, None, :] <= qpos[:, :, None]
+    assert pk.query_tile(S, 2, 16) == 8       # every family: two tiles
+    np.testing.assert_allclose(np.where(live, np.asarray(got), 0.0),
+                               np.where(live, np.asarray(want), 0.0),
+                               atol=2e-5, rtol=2e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -187,7 +244,7 @@ def test_engine_prefill_stats_counter():
 
 
 # ---------------------------------------------------------------------------
-# scan-invariant pool: decode must not round-trip the pool through the scan
+# scan-invariant pool
 # ---------------------------------------------------------------------------
 
 def _scanned_cfg():
@@ -195,52 +252,6 @@ def _scanned_cfg():
     return get_smoke_config("yi_6b").replace(
         d_model=128, num_heads=2, num_kv_heads=2, head_dim=32, d_ff=256,
         vocab_size=256, dsa=None, num_layers=2, first_k_dense=0)
-
-
-def test_decode_step_donated_pool_no_oPool_copy():
-    """Regression for the scan-copy hazard: with the pool donated, the
-    compiled decode step's TEMP allocation must be independent of pool
-    capacity and far below the pool size.  The old stacked-xs/ys layout
-    materialized the whole pool as fresh scan outputs (temp growing with
-    the pool) every step regardless of the in-place attention kernel."""
-    import os
-    if os.environ.get("JAX_PALLAS_INTERPRET", "").lower() not in \
-            ("", "0", "false"):
-        pytest.skip("interpret mode emulates kernels through callbacks "
-                    "that materialize pool copies; the aliasing property "
-                    "under test belongs to the production dispatch")
-    cfg = _scanned_cfg()
-    model = get_model(cfg)
-    params, _ = model.init(jax.random.key(0), cfg)
-    B, bs = 2, 8
-    lens = jnp.asarray([5, 17], jnp.int32)
-    tok = jnp.asarray([[7], [9]], jnp.int32)
-
-    def compiled_for(mb):
-        pool, _ = model.init_paged_cache(cfg, B * mb + 1, bs)
-        tables = jnp.asarray(np.arange(B * mb).reshape(B, mb)
-                             .astype(np.int32))
-        step = jax.jit(lambda p, t, c, bt, ln: model.decode_step(
-            p, t, cfg, c, ln, block_tables=bt), donate_argnums=(2,))
-        compiled = step.lower(params, tok, pool, tables, lens).compile()
-        return step, pool, tables, compiled
-
-    step, pool, tables, big = compiled_for(128)
-    try:
-        temp_big = big.memory_analysis().temp_size_in_bytes
-        temp_small = compiled_for(8)[3].memory_analysis().temp_size_in_bytes
-    except Exception:
-        pytest.skip("backend lacks compiled.memory_analysis()")
-    pool_bytes = tree_bytes(pool)
-    # temp must not grow with pool capacity (16x more blocks, same temp)...
-    assert temp_big <= temp_small + 4096, (temp_small, temp_big)
-    # ...and stays far below the pool a scan round-trip would materialize
-    assert temp_big < pool_bytes / 4, (temp_big, pool_bytes)
-    # and the donated buffers are actually reused end to end
-    ptrs = {l.unsafe_buffer_pointer() for l in jax.tree.leaves(pool)}
-    lg, new_pool = step(params, tok, pool, tables, lens)
-    new_ptrs = {l.unsafe_buffer_pointer() for l in jax.tree.leaves(new_pool)}
-    assert ptrs == new_ptrs
 
 
 def test_scanned_decode_matches_contiguous():

@@ -20,7 +20,7 @@ Covers the PR-9 robustness surface:
     respawn -> fail-back, and the ``bind_dp_router`` health wiring;
   * ``DPRouter`` rank health: drop reroutes immediately, restore
     re-adds, rebalance ignores dead ranks;
-  * property test (hypothesis when installed): refcount conservation /
+  * property test (hypothesis): refcount conservation /
     no-double-free / free-list integrity across BOTH pools under random
     prefill/migrate/fault/skew/pin interleavings.
 """
@@ -34,7 +34,7 @@ import jax
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from repro.async_rl.router import DPRouter
 from repro.configs import get_smoke_config
 from repro.faults import FaultInjector

@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.rl.async_is import (async_is_loss, calibration_mask,
                                pad_or_drop_group, staleness_keep)

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs import get_smoke_config
 from repro.configs.base import DSAConfig, MTPConfig
@@ -34,9 +36,6 @@ from repro.core.paging import blocks_for
 from repro.models import get_model
 from repro.serving import ContinuousEngine, Request
 from repro.serving.session import AgentSession
-
-from tests._hypothesis_compat import given, settings
-from tests._hypothesis_compat import strategies as st
 
 _KW = dict(max_batch=2, block_size=8, num_blocks=32, max_len=64)
 _MTP = MTPConfig(num_predict=3, share_params=True)

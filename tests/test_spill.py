@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from repro.configs import get_smoke_config
 from repro.models import get_model
 from repro.serving import (AgentSession, CacheFull, ContinuousEngine,

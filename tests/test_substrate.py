@@ -6,8 +6,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
-from jax.sharding import PartitionSpec as P
+from hypothesis import given, settings, strategies as st
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs import get_smoke_config
 from repro.configs.base import ModelConfig
@@ -23,13 +23,12 @@ from repro.sharding.rules import Builder, make_rules, resolve_spec
 # ---------------------------------------------------------------------------
 
 def _mesh():
-    from repro.launch.mesh import auto_axis_types
-    return jax.make_mesh((1, 1), ("data", "model"), **auto_axis_types(2))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def test_resolve_spec_divisibility_guard():
-    from repro.launch.mesh import auto_axis_types
-    mesh = jax.make_mesh((1,), ("model",), **auto_axis_types(1))
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,))
     rules = {"heads": "model"}
     # size-1 axes always divide; use a fake 16-way mesh via rules math
     spec = resolve_spec(("heads", None), (8, 4), rules, mesh)

@@ -1,0 +1,7 @@
+"""ttft_p95_ms: 95th percentile time to first token over the requests due
+in the window, timed from when each was due."""
+from bench.harness.readings import pct, ttfts_ms
+
+
+def read(run):
+    return pct(ttfts_ms(run), 95)

@@ -34,7 +34,7 @@ def main():
     s = engine.stats
     print(f"scheduler: {s['decode_steps']} decode steps for "
           f"{s['decode_tokens']} tokens across {s['prefills']} requests "
-          f"(admissions at steps {s['admit_steps']})")
+          f"in {s['steps']} engine steps")
 
     # context management on the synthetic multi-hop search env
     print("\ncontext management (hierarchical vs keep-recent, one episode):")

@@ -12,8 +12,10 @@ Exits non-zero if the exported trace fails validation — CI runs this as
 the observability smoke.
 
 Open the output at https://ui.perfetto.dev (or chrome://tracing): one
-row per thread, ``engine.step`` spans on the serve thread with request
-lifecycle instants between them.
+row per thread, ``engine.step`` spans on the serve thread holding the
+``engine.admit`` / ``engine.prefill`` / ``engine.decode`` /
+``engine.sample`` / ``engine.retire`` spans, with request lifecycle
+instants between them.
 """
 import sys
 

@@ -79,15 +79,6 @@ def trace_buffer_limit() -> int:
     return int(os.environ.get("REPRO_TRACE_BUFFER", "200000"))
 
 
-def admit_steps_window() -> int:
-    """Bound on the ``stats["admit_steps"]`` history deque
-    (``REPRO_ADMIT_STEPS_WINDOW``, default 4096 admissions).  The old
-    unbounded list grew one entry per admission forever — a memory leak
-    on a long-running serve loop; the deque keeps the most recent window
-    (tests only ever inspect recent admissions)."""
-    return int(os.environ.get("REPRO_ADMIT_STEPS_WINDOW", "4096"))
-
-
 def fault_spec() -> str:
     """Deterministic fault-injection spec (``REPRO_FAULTS``, default "").
 

@@ -128,6 +128,7 @@ def paged_gqa_attend(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     window=window, softcap=softcap, eff=resolve_impl(impl))
 
 
+# Read by name: device traces call these ops `_gqa_jit`; do not rename.
 @functools.partial(jax.jit, static_argnames=("window", "softcap", "eff"))
 def _gqa_jit(q, k_pool, v_pool, block_tables, seq_lens, *,
              window: int, softcap: float, eff: str) -> jax.Array:
@@ -255,6 +256,7 @@ def paged_indexer_scores(q_idx: jax.Array, w_head: jax.Array,
                         eff=resolve_impl(impl))
 
 
+# Read by name: device traces call these ops `_indexer_jit`; do not rename.
 @functools.partial(jax.jit, static_argnames=("eff",))
 def _indexer_jit(q_idx, w_head, k_pool, block_tables, seq_lens, *,
                  eff: str) -> jax.Array:

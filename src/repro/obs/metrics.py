@@ -220,17 +220,13 @@ class StatsView(Mapping):
     iteration — while every scalar lives in the shared registry under
     ``<prefix>.<key>``, so the same numbers show up in ``snapshot()``,
     benchmark JSON, and the stats dict with no double accounting.
-
-    Non-scalar entries (``admit_steps``'s bounded deque) are held locally
-    and passed through untouched.
     """
 
     def __init__(self, registry: MetricsRegistry, prefix: str,
-                 keys: Iterable[str], local: Optional[Dict] = None):
+                 keys: Iterable[str]):
         self._registry = registry
         self._prefix = prefix
-        self._local = dict(local or {})
-        self._keys = [k for k in keys if k not in self._local]
+        self._keys = list(keys)
         for k in self._keys:                    # materialize zeros eagerly:
             registry.inc(self._name(k), 0)      # dict(view) shows every key
 
@@ -239,35 +235,29 @@ class StatsView(Mapping):
 
     # ------------------------------------------------------- mapping surface
     def __getitem__(self, key: str):
-        if key in self._local:
-            return self._local[key]
         if key not in self._keys:
             raise KeyError(key)
         return self._registry.counter(self._name(key))
 
     def __setitem__(self, key: str, value) -> None:
-        if key in self._local:
-            self._local[key] = value
-            return
         if key not in self._keys:
             self._keys.append(key)
         self._registry.set_counter(self._name(key), value)
 
     def __iter__(self) -> Iterator[str]:
         yield from self._keys
-        yield from self._local
 
     def __len__(self) -> int:
-        return len(self._keys) + len(self._local)
+        return len(self._keys)
 
     def __contains__(self, key) -> bool:
-        return key in self._local or key in self._keys
+        return key in self._keys
 
     def __repr__(self) -> str:  # pragma: no cover - debugging sugar
         return f"StatsView({dict(self)!r})"
 
     def reset(self, values: Optional[Mapping] = None) -> None:
-        """Zero every scalar (or load ``values``); clear local deques.
+        """Zero every counter (or load ``values``).
 
         Supports the benchmark idiom ``eng.stats = {k: 0 ...}`` via the
         owner's property setter."""
@@ -275,12 +265,3 @@ class StatsView(Mapping):
         for k in self._keys:
             v = values.get(k, 0)
             self._registry.set_counter(self._name(k), int(v))
-        for k, cur in self._local.items():
-            if hasattr(cur, "clear"):
-                cur.clear()
-                v = values.get(k)
-                if v is not None and hasattr(v, "__iter__") \
-                        and hasattr(cur, "extend"):
-                    cur.extend(v)
-            elif k in values:
-                self._local[k] = values[k]

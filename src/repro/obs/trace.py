@@ -1,30 +1,47 @@
-"""Per-request / per-step tracing, exportable as Chrome trace-event JSON.
+"""Per-request / per-step tracing, exportable as Chrome trace-event JSON
+and mirrored into the JAX profiler.
 
 The continuous engine's behavior under mixed agentic traffic — chunked
 prefills interleaving with decode, speculative rounds, weight-push drain
 barriers — is fundamentally a *timeline* artifact; counters alone cannot
 show why one request's TTFT blew past the SLO.  ``Tracer`` records:
 
-* **engine-step spans** (``B``/``E`` pairs): one per ``step()``, with
-  batch occupancy, waiting-queue depth, live tokens, and pool utilization
-  attached as args;
+* **engine spans** (``Tracer.span``: a ``B``/``E`` pair): ``engine.step``
+  around each ``step()`` (batch occupancy, waiting-queue depth, live
+  tokens and pool use as args), and inside it ``engine.admit``,
+  ``engine.prefill`` (``req``, ``start``, ``span``), ``engine.decode``
+  (dispatch to logits on the host; ``rows`` = request ids),
+  ``engine.sample`` (``rows``) and ``engine.retire``; the front end adds
+  ``frontend.harvest`` after each step;
 * **request lifecycle instants**: ``req.submit`` -> ``req.admitted``
-  (cached tokens + block count attached) -> ``req.prefill`` per chunk ->
-  ``req.first_token`` -> ``req.spec_round`` per speculative verification
-  -> ``req.finished`` (``out_version`` attached);
+  (slot, cached tokens, blocks, engine step) -> ``req.prefill`` per
+  chunk -> ``req.first_token`` -> ``req.spec_round`` per speculative
+  verification -> ``req.finished`` (``out_version`` attached);
 * **engine events**: ``jit.compile`` whenever an engine jit actually
   traces (the recompile hazard, now first-class), ``push.requested`` /
   ``push.applied`` with the drain duration.
 
+Every span and instant is also entered as a ``jax.profiler.TraceAnnotation``
+(instants as zero-length ones) with the same name and args, so a running
+profile shows the engine's own spans on the host plane, on the device
+trace's clock.
+
 The export (``Tracer.export``) is the Chrome trace-event format —
-``{"traceEvents": [...]}`` with microsecond ``ts`` — directly loadable in
-Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``.
+``{"traceEvents": [...]}`` with ``ts`` in microseconds of
+``time.perf_counter()`` since ``Tracer._t0`` — directly loadable in
+Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``.  Its
+``otherData.clock_offset_ns`` maps that clock onto the profiler's:
+``ts * 1e3 + clock_offset_ns`` is the event's time in the profiler's
+nanoseconds (``time.time_ns()``, the clock of an xplane's
+``profile_start_time``), so an export and an xplane of the same run line
+up.
 
 **Gating**: tracing defaults off and is enabled per engine
 (``ContinuousEngine(tracer=Tracer(enabled=True))``) or process-wide via
 ``REPRO_TRACE=1`` (``repro.flags.trace_enabled``).  Disabled, every hook
-is one attribute check — no buffer growth, no timestamps taken, no
-behavior change (the oracle parity suites run byte-identical either way).
+is one attribute check — ``span`` hands back the shared ``NO_SPAN``; no
+buffer growth, no timestamps taken, no behavior change (the oracle
+parity suites run byte-identical either way).
 
 ``validate_chrome_trace`` is the schema checker CI runs against an
 exported trace: required keys, non-decreasing ``ts``, strictly matched
@@ -37,6 +54,65 @@ import json
 import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+
+class _NoSpan:
+    """What ``Tracer.span`` returns while tracing is off: does nothing."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set(self, **args) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
+class _Span:
+    """An enabled span: a Chrome ``B``/``E`` pair around a profiler
+    annotation of the same name and args."""
+    __slots__ = ("_tr", "_name", "_args", "_end_args", "_ann")
+
+    def __init__(self, tr: "Tracer", name: str, args: dict):
+        self._tr, self._name, self._args = tr, name, args
+        self._end_args: dict = {}
+
+    def __enter__(self):
+        self._tr._phase("B", self._name, self._args)
+        self._ann = TraceAnnotation(self._name, **self._args)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(*exc)
+        self._tr._phase("E", self._name, self._end_args)
+        return False
+
+    def set(self, **args) -> None:
+        """Args known only when the span closes (``E`` event args, and
+        metadata of the profiler's event)."""
+        self._end_args.update(args)
+        self._ann.set_metadata(**args)
+
+
+def _clock_offset_ns() -> tuple:
+    """``time.time_ns() - time.perf_counter_ns()``, from the tightest of
+    five bracketed reads: (offset, bracket width in ns)."""
+    best = None
+    for _ in range(5):
+        a = time.perf_counter_ns()
+        w = time.time_ns()
+        b = time.perf_counter_ns()
+        if best is None or b - a < best[1]:
+            best = (w - (a + b) // 2, b - a)
+    return best
 
 
 class Tracer:
@@ -74,24 +150,37 @@ class Tracer:
             self._events.append(ev)
 
     # ------------------------------------------------------------ recording
+    def _phase(self, ph: str, name: str, args: dict) -> None:
+        self._emit({"name": name, "ph": ph, "ts": self.now_us(),
+                    "pid": 0, "tid": self._tid(), "args": args})
+
     def instant(self, name: str, **args) -> None:
+        """A point event; in a running profile, a zero-length annotation
+        with the same args."""
         if not self.enabled:
             return
         self._emit({"name": name, "ph": "i", "s": "t",
                     "ts": self.now_us(), "pid": 0, "tid": self._tid(),
                     "args": args})
+        with TraceAnnotation(name, **args):
+            pass
 
     def begin(self, name: str, **args) -> None:
-        if not self.enabled:
-            return
-        self._emit({"name": name, "ph": "B", "ts": self.now_us(),
-                    "pid": 0, "tid": self._tid(), "args": args})
+        if self.enabled:
+            self._phase("B", name, args)
 
     def end(self, name: str, **args) -> None:
+        if self.enabled:
+            self._phase("E", name, args)
+
+    def span(self, name: str, **args):
+        """Context manager around one unit of work: a ``B``/``E`` pair in
+        the export and a ``TraceAnnotation`` in any running profile.
+        Disabled, the shared ``NO_SPAN``.  ``.set(**args)`` on the span
+        adds args known only at its end."""
         if not self.enabled:
-            return
-        self._emit({"name": name, "ph": "E", "ts": self.now_us(),
-                    "pid": 0, "tid": self._tid(), "args": args})
+            return NO_SPAN
+        return _Span(self, name, args)
 
     # -------------------------------------------------------------- reading
     @property
@@ -110,8 +199,15 @@ class Tracer:
         threads can interleave slightly out of order)."""
         with self._lock:
             events = sorted(self._events, key=lambda e: e["ts"])
+        offset, width = _clock_offset_ns()
         obj = {"traceEvents": events, "displayTimeUnit": "ms",
-               "otherData": {"dropped_events": self.dropped}}
+               "otherData": {
+                   "dropped_events": self.dropped,
+                   # ts * 1e3 + clock_offset_ns = the profiler's ns
+                   "clock_offset_ns": round(self._t0 * 1e9) + offset,
+                   "clock_offset": "time.time_ns() - time.perf_counter_ns()"
+                                   f" at export, bracketed within {width} ns,"
+                                   " plus the tracer epoch in ns"}}
         if path is not None:
             with open(path, "w") as f:
                 json.dump(obj, f)

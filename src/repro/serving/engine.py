@@ -54,6 +54,10 @@ class Request:
     t_submit: Optional[float] = None
     t_first: Optional[float] = None
     t_finish: Optional[float] = None
+    # ContinuousEngine: one perf_counter stamp per token of ``out``, taken
+    # when the token was sampled (t_tokens[0] == t_first; the tokens of
+    # one speculative round share its stamp)
+    t_tokens: Optional[np.ndarray] = None
     # fault-tolerance terminal state: exactly one of ``out`` / ``error``
     # is set when the request leaves the engine.  ``error`` is one of the
     # typed ``repro.serving.errors`` classes (or the isolated fault that

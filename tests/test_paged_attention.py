@@ -28,6 +28,7 @@ from repro.kernels.paged_attention.kernel import (
 from repro.kernels.paged_attention.ops import (_blocked_gqa, _blocked_mla,
                                                _blocked_indexer)
 from repro.models import get_model
+from repro.obs.trace import Tracer
 from repro.serving import ContinuousEngine, Request
 
 
@@ -256,11 +257,21 @@ def test_engine_greedy_byte_parity_gather_vs_inplace(family):
     o_pal, e_pal = _serve(cfg, params, "pallas", _PLENS, _MAXNEW, **_KW)
     for a, b in zip(o_ref, o_pal):
         np.testing.assert_array_equal(a, b)
-    assert e_pal.stats["gather_bytes_saved"] > 0
-    assert e_ref.stats["gather_bytes_saved"] == 0
-    # attn_impl covers prefill too: spans read blocks in place as well
-    assert e_pal.stats["prefill_gather_bytes_saved"] > 0
-    assert e_ref.stats["prefill_gather_bytes_saved"] == 0
+
+
+@pytest.mark.parametrize("family,kernel", [("gqa", "_gqa_jit"),
+                                           ("dsa", "_indexer_jit")])
+def test_decode_program_keeps_the_kernel_names_traces_are_read_by(family,
+                                                                  kernel):
+    """Device traces name a kernel's ops after its jitted function; the
+    per-kernel roofline readers find the kernels by these names."""
+    cfg = _family_cfg(family)
+    params, _ = get_model(cfg).init(jax.random.key(0), cfg)
+    eng = ContinuousEngine(cfg, params, attn_impl="pallas", **_KW)
+    lowered = eng._decode.lower(
+        eng.params, jnp.zeros((eng.max_batch, 1), jnp.int32), eng.pool,
+        jnp.asarray(eng.tables), jnp.asarray(eng.lengths))
+    assert kernel in lowered.as_text()
 
 
 def test_decode_compiles_once_across_admit_retire():
@@ -270,6 +281,8 @@ def test_decode_compiles_once_across_admit_retire():
     cfg = _family_cfg("gqa")
     params, _ = get_model(cfg).init(jax.random.key(0), cfg)
     _, eng = _serve(cfg, params, None, [5, 17, 9, 33, 1, 26],
-                    [3, 9, 5, 12, 1, 7], **_KW)
-    assert any(s > 0 for s in eng.stats["admit_steps"])   # churn happened
+                    [3, 9, 5, 12, 1, 7], tracer=Tracer(enabled=True), **_KW)
+    steps = [e["args"]["step"] for e in eng.tracer.export()["traceEvents"]
+             if e["name"] == "req.admitted"]
+    assert any(s > 0 for s in steps)                       # churn happened
     assert eng._decode._cache_size() == 1

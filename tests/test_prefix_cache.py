@@ -26,6 +26,7 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.models import get_model
+from repro.obs.trace import Tracer
 from repro.serving import (AgentSession, CacheFull, ContinuousEngine,
                            PagedKVCache, PrefixCache, Request, RequestShed,
                            ServingEngine)
@@ -350,7 +351,8 @@ def test_hybrid_continuous_engine_matches_oracle(chunk):
     oracle.serve(oreqs)
 
     eng = ContinuousEngine(cfg, params, max_batch=2, block_size=8,
-                           num_blocks=24, max_len=64, prefill_chunk=chunk)
+                           num_blocks=24, max_len=64, prefill_chunk=chunk,
+                           tracer=Tracer(enabled=True))
     assert eng.prefix is None      # recurrent state cannot be re-aliased
     reqs = [Request(prompt=p, max_new=m) for p, m in zip(prompts, maxnew)]
     eng.serve(reqs)
@@ -358,7 +360,9 @@ def test_hybrid_continuous_engine_matches_oracle(chunk):
         np.testing.assert_array_equal(r.out, o.out)
     # 4 requests through 2 slots: slot REUSE (and so mamba2 state reset on
     # admission) must have happened mid-flight
-    assert any(s > 0 for s in eng.stats["admit_steps"])
+    assert any(e["args"]["step"] > 0
+               for e in eng.tracer.export()["traceEvents"]
+               if e["name"] == "req.admitted")
     assert eng.kv.free_blocks == eng.kv.num_blocks
 
 

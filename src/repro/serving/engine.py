@@ -103,6 +103,33 @@ def sample_token(logits_row: np.ndarray, temperature: float, rng) -> int:
     return int(rng.choice(len(p), p=p))
 
 
+def sample_rows(logits: jax.Array, temps: jax.Array, key: jax.Array,
+                logprobs: Optional[str] = None):
+    """``sample_token`` for a batch of rows, on the device, inside jit.
+
+    logits (B, V) in any float dtype, temps float32 (B,).  Rows with
+    ``T <= 0`` take the first argmax of the float32 logits, as
+    ``sample_token`` does; the others draw exactly from
+    ``softmax(float32(logits) / T)`` over the whole vocabulary (Gumbel-max,
+    ``jax.random.categorical``).  ``logprobs`` picks the logprob returned
+    for each drawn token, in float32 and in the host's arithmetic order:
+    ``"true"`` is ``log_softmax(logits)[tok]``, ``"scaled"`` is
+    ``log_softmax((logits - max) / max(T, 1e-6))[tok]`` (the rollout
+    convention: greedy rows read ~0), ``None`` computes none and returns
+    zeros.  Returns (tokens int32 (B,), logprobs float32 (B,))."""
+    x = logits.astype(jnp.float32)
+    t = temps[:, None]
+    drawn = jax.random.categorical(key, x / jnp.where(t > 0, t, 1.0), axis=-1)
+    tok = jnp.where(temps > 0, drawn, jnp.argmax(x, axis=-1)).astype(jnp.int32)
+    if logprobs is None:
+        return tok, jnp.zeros(temps.shape, jnp.float32)
+    z = x - jnp.max(x, axis=-1, keepdims=True)
+    if logprobs == "scaled":
+        z = z / jnp.maximum(t, 1e-6)
+    picked = jnp.take_along_axis(z, tok[:, None], axis=-1)[:, 0]
+    return tok, picked - jnp.log(jnp.sum(jnp.exp(z), axis=-1))
+
+
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 8,
                  max_len: int = 512, seed: int = 0):

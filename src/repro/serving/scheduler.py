@@ -23,7 +23,12 @@ keeps ``max_batch`` decode *slots* and, every step,
      That is O(live tokens) HBM traffic instead of the old full-view
      ``paged_view`` gather, which copied B × max_blocks × block_size
      tokens per call regardless of occupancy.  ``attn_impl="ref"``
-     restores the gather for both phases (the parity oracle).
+     restores the gather for both phases (the parity oracle).  The same
+     program samples every row's next token (``sample_rows``: per-row
+     temperature, greedy at T <= 0) and its logprob, so a step hands the
+     host B tokens, not B × V logits.  A prefill program hands back only
+     its last position's logits, and the final span's first token is
+     drawn from them by the same sampler (``_draw``).
 
 Prefix reuse (``prefix_cache=True``, attention-cache families): on admit
 the engine asks the radix cache (``repro.serving.prefix_cache``) for the
@@ -118,7 +123,7 @@ from repro.faults import FaultInjector
 from repro.models import get_model
 from repro.obs.metrics import MetricsRegistry, StatsView
 from repro.obs.trace import NO_SPAN, Tracer
-from repro.serving.engine import Request, sample_token
+from repro.serving.engine import Request, sample_rows, sample_token
 from repro.serving.errors import (DeadlineExceeded, EngineOverloaded,
                                   RequestCancelled, RequestShed)
 from repro.serving.paged import CacheFull, PagedKVCache, blocks_for
@@ -147,6 +152,21 @@ class _Active:
         self.pos = pos                       # next prefill position
         self.h_last: Optional[np.ndarray] = None   # (D,) trunk hidden at the
         # last CACHED position (spec_steps only: the MTP draft input)
+
+
+class _Draw:
+    """One row's token and logprob as the device sampler drew them, the
+    ``row`` that ``ContinuousEngine._sample`` hands out.  ``len()`` is the
+    vocabulary it was drawn over, as a logits row's is, so code wrapping
+    ``_sample`` (the benchmark's fault checks alter a token by id) reads
+    either kind of row alike."""
+    __slots__ = ("token", "logprob", "vocab")
+
+    def __init__(self, token: int, logprob: float, vocab: int):
+        self.token, self.logprob, self.vocab = token, logprob, vocab
+
+    def __len__(self) -> int:
+        return self.vocab
 
 
 def _emit_pending(s: _Active) -> None:
@@ -306,17 +326,32 @@ class ContinuousEngine:
         self.lengths = np.zeros((max_batch,), np.int32)
         self.slots: List[Optional[_Active]] = [None] * max_batch
         self.waiting: collections.deque = collections.deque()
+        # host sampler: the speculative path only (its verified span
+        # logits are on the host); every other token is drawn on the
+        # device (the decode program, ``_draw``) from fold_in(key(seed),
+        # draw counter).
+        # The key is the seed's two 32-bit words, so seeds past 2**32
+        # stay distinct; it is an argument of the programs, not a
+        # constant, so their compiled form (and the persistent compile
+        # cache's key) does not depend on the seed.
         self._rng = np.random.default_rng(seed)
+        self._key_words = np.array([(seed >> 32) & 0xFFFFFFFF,
+                                    seed & 0xFFFFFFFF], np.uint32)
+        self._draws = 0
+        self._logprobs = ("true" if true_logprobs else "scaled") \
+            if capture_logprobs else None
         # the historical stats dict, now a VIEW over registry counters
         # (same keys, same reads/writes); "compiles" counts actual jit
         # traces of the engine's compiled steps — the re-jitting hazard as
         # a first-class metric; "sample_ns" sums the host time spent in
-        # ``_sample`` (softmax, draw, logprob)
+        # ``_sample`` (handing a device-drawn token and logprob to its
+        # slot; on the speculative path, softmax, draw and logprob);
+        # "device_sampled_rows" counts the rows drawn on the device
         self._stats = StatsView(
             self.registry, "engine",
             ["steps", "prefills", "decode_steps", "decode_tokens",
              "prefill_tokens", "cached_tokens", "cow_forks", "chunk_steps",
-             "sample_ns",
+             "sample_ns", "device_sampled_rows",
              # speculative decode (spec_steps > 0): drafted vs accepted
              # counts; spec_rounds counts (slot, step) verifications that
              # drafted at least one token
@@ -345,11 +380,20 @@ class ContinuousEngine:
         # donate the pool through the hot jits: paged_update then scatters
         # into the live buffer instead of copying the whole pool every step
         # (hybrid decode donates only the KV pool — _ssm_restore must read
-        # the pre-step recurrent state, which donation would invalidate)
-        self._decode = jax.jit(self._hybrid_decode_fn if self.hybrid
-                               else self._decode_fn, donate_argnums=(2,))
-        self._prefill = jax.jit(self._hybrid_prefill_fn if self.hybrid
-                                else self._prefill_fn, donate_argnums=(2,))
+        # the pre-step recurrent state, which donation would invalidate).
+        # Decode samples inside its program.  A (non-speculative) prefill
+        # program hands back its last position's logits, and ``_draw``
+        # samples them on the final span: one program for every span
+        # length, where a sampler in each prefill program would be lowered
+        # and compiled once per span length.
+        hy = "_hybrid" if self.hybrid else ""
+        self._decode = jax.jit(self._last_position(hy + "_decode_fn", True),
+                               donate_argnums=(2,))
+        self._prefill = jax.jit(
+            self._prefill_fn if self.spec_steps
+            else self._last_position(hy + "_prefill_fn", False),
+            donate_argnums=(2,))
+        self._draw = jax.jit(self._draw_fn)
         # donating the pool makes the COW fork a single-block in-place
         # write instead of a whole-pool HBM round trip
         self._cow = jax.jit(self._cow_fn, donate_argnums=(0,))
@@ -395,6 +439,41 @@ class ContinuousEngine:
         self.tracer.instant("jit.compile", fn=fn)
 
     # ------------------------------------------------------------------ jit
+    def _last_position(self, name: str, sample: bool):
+        """The model step ``self.<name>`` (args -> (logits (B,S,V), pool))
+        cut to each row's last position, as one program: (*args) ->
+        (logits (B,V), pool), or with ``sample`` (*args,
+        *``_sampler_args``) -> (tokens (B,), logprobs (B,), pool), drawn
+        by ``_draw_fn`` inside it.  The program keeps the step's name,
+        which names its XLA module (``jit__decode_fn``,
+        ``jit__prefill_fn``): device traces are read by module name."""
+        step_fn = getattr(self, name)
+
+        def program(*args):
+            if not sample:
+                logits, pool = step_fn(*args)
+                return logits[:, -1], pool
+            *args, temps, key_words, draw = args
+            logits, pool = step_fn(*args)
+            return (*self._draw_fn(logits[:, -1], temps, key_words, draw),
+                    pool)
+        program.__name__ = program.__qualname__ = name
+        return program
+
+    def _draw_fn(self, logits, temps, key_words, draw):
+        """``sample_rows`` keyed fold_in(key(seed), draw): (tokens (B,),
+        logprobs (B,)) for logits (B, V)."""
+        key = jax.random.fold_in(jax.random.wrap_key_data(key_words), draw)
+        return sample_rows(logits, temps, key, self._logprobs)
+
+    def _sampler_args(self, temps: np.ndarray) -> tuple:
+        """The trailing arguments of a draw's next call: per-row
+        temperatures (float32), the seed's key words and the draw counter
+        (the PRNG key is fold_in(key(seed), counter)).  All are traced, so
+        none of them recompiles."""
+        self._draws += 1
+        return temps, self._key_words, np.int32(self._draws & 0x7FFFFFFF)
+
     def _decode_fn(self, params, tok, pool, tables, lengths):
         self._compiled("decode")
         return self.model.decode_step(params, tok, self.cfg, pool, lengths,
@@ -1069,16 +1148,23 @@ class ContinuousEngine:
                 args.append(jnp.asarray(slot, jnp.int32))
             if self.spec_steps:
                 logits, hid, self.pool = self._prefill(*args)
-            else:
+            else:           # logits (1, V): the span's last position only
                 logits, self.pool = self._prefill(*args)
             tr.instant("req.prefill", req=s.req.rid, start=start, span=real)
             s.pos = start + real
             if s.pos < plen:
                 return
-            # final span: sample token 1
-            lg = np.asarray(logits[0, real - 1], np.float32)
+            # final span: token 1
+            if self.spec_steps:
+                row = np.asarray(logits[0, real - 1], np.float32)
+            else:
+                temps = np.asarray([s.req.temperature], np.float32)
+                tok, lp = jax.device_get(
+                    self._draw(logits, *self._sampler_args(temps)))
+                row = _Draw(int(tok[0]), float(lp[0]), self.cfg.vocab_size)
+                self.stats["device_sampled_rows"] += 1
             with tr.span("engine.sample", rows=[s.req.rid]):
-                s.pending, s.pending_lp = self._sample(lg, s.req.temperature)
+                s.pending, s.pending_lp = self._sample(row, s.req.temperature)
                 # the first generated token is KNOWN here (``pending``; it
                 # is emitted unchanged) — this is the TTFT stamp
                 s.pending_t = time.perf_counter()
@@ -1122,17 +1208,16 @@ class ContinuousEngine:
             old_ssm = self.pool["ssm"] if (self.hybrid and prefilling) \
                 else None
             tok = np.zeros((self.max_batch, 1), np.int32)
+            temps = np.zeros((self.max_batch,), np.float32)
             for i in active:
                 tok[i, 0] = self.slots[i].pending
-            if self.hybrid:
-                logits, self.pool = self._decode(
-                    self.params, jnp.asarray(tok), self.pool["kv"],
-                    self.pool["ssm"], jnp.asarray(self.tables),
-                    jnp.asarray(self.lengths))
-            else:
-                logits, self.pool = self._decode(
-                    self.params, jnp.asarray(tok), self.pool,
-                    jnp.asarray(self.tables), jnp.asarray(self.lengths))
+                temps[i] = self.slots[i].req.temperature
+            pool = (self.pool["kv"], self.pool["ssm"]) if self.hybrid \
+                else (self.pool,)
+            toks, lps, self.pool = self._decode(
+                self.params, jnp.asarray(tok), *pool,
+                jnp.asarray(self.tables), jnp.asarray(self.lengths),
+                *self._sampler_args(temps))
             if old_ssm is not None:
                 # a decode step must not advance the recurrent state of
                 # slots whose prompt is still mid-chunked-prefill
@@ -1140,17 +1225,19 @@ class ContinuousEngine:
                 mask[prefilling] = True
                 self.pool = dict(self.pool, ssm=self._ssm_restore(
                     self.pool["ssm"], old_ssm, jnp.asarray(mask)))
-            lg = np.asarray(logits[:, 0], np.float32)
+            toks, lps = jax.device_get((toks, lps))
+        V = self.cfg.vocab_size
         with tr.span("engine.sample", rows=rows):
             for i in active:
                 s = self.slots[i]
                 _emit_pending(s)
                 self.lengths[i] += 1        # pending now lives in the cache
-                s.pending, s.pending_lp = self._sample(lg[i],
-                                                       s.req.temperature)
+                s.pending, s.pending_lp = self._sample(
+                    _Draw(int(toks[i]), float(lps[i]), V), s.req.temperature)
                 s.pending_t = time.perf_counter()
         self.stats["decode_steps"] += 1
         self.stats["decode_tokens"] += len(active)
+        self.stats["device_sampled_rows"] += len(active)
 
     # ------------------------------------------------------- speculative
     @property
@@ -1266,8 +1353,20 @@ class ContinuousEngine:
         self.lengths[i] = new_len
 
     # ----------------------------------------------------------- sampling
-    def _sample(self, row: np.ndarray, temperature: float):
+    def _sample(self, row, temperature: float):
+        """Hand one row's token and logprob to its slot.  ``row`` is the
+        device sampler's draw (``_Draw``: the decode program and the final
+        prefill span), or on the speculative path the row's float32 logits,
+        sampled here on the host with the same conventions."""
         t0 = time.perf_counter_ns()
+        if isinstance(row, _Draw):
+            tok, lp = row.token, row.logprob
+        else:
+            tok, lp = self._host_sample(row, temperature)
+        self._stats["sample_ns"] += time.perf_counter_ns() - t0
+        return tok, lp
+
+    def _host_sample(self, row: np.ndarray, temperature: float):
         tok = sample_token(row, temperature, self._rng)
         lp = 0.0
         if self.capture_logprobs:
@@ -1285,5 +1384,4 @@ class ContinuousEngine:
                 # logprobs are comparable in the IS ratios downstream
                 z = (row - row.max()) / max(temperature, 1e-6)
             lp = float(z[tok] - np.log(np.exp(z).sum()))
-        self._stats["sample_ns"] += time.perf_counter_ns() - t0
         return tok, lp

@@ -8,7 +8,8 @@
     xplane twin;
   * ``Request.t_tokens``: one stamp per emitted token, ``t_tokens[0] ==
     t_first``, never decreasing, shared by a speculative round's tokens;
-  * ``stats["sample_ns"]``: host time inside ``_sample`` only;
+  * ``stats["sample_ns"]``: host time inside ``_sample`` only, which hands
+    the device sampler's draws out to the slots;
   * the benchmark's readers of these (``host_sample_ms``, ``itl_p99_ms``)
     read a traced smoke run, and read nothing where the program lacks the
     counter and the stamps.
@@ -179,6 +180,10 @@ def test_t_tokens_one_stamp_per_token(path):
 
 
 def test_sample_ns_counts_only_inside_sample():
+    """Every token reaches its slot through one ``_sample`` call, which
+    hands out what the device sampler drew (a row as long as the
+    vocabulary, not logits); ``sample_ns`` counts the time inside it and
+    ``device_sampled_rows`` the rows drawn on the device."""
     cfg, params = _params("gqa")
     eng = ContinuousEngine(cfg, params, capture_logprobs=True,
                            true_logprobs=True, **_KW)
@@ -186,6 +191,7 @@ def test_sample_ns_counts_only_inside_sample():
 
     def wrapped(row, temperature):              # host time outside the body
         time.sleep(0.002)
+        assert not isinstance(row, np.ndarray) and len(row) == cfg.vocab_size
         calls.append(1)
         return inner(row, temperature)
     eng._sample = wrapped
@@ -193,11 +199,14 @@ def test_sample_ns_counts_only_inside_sample():
     eng.serve(reqs)
     first = eng.stats["sample_ns"]
     assert len(calls) == sum(r.max_new for r in reqs)
+    assert eng.stats["device_sampled_rows"] == len(calls)
     assert 0 < first < len(calls) * 2e6
     eng.serve(_requests(cfg, temperature=1.0))
     assert eng.stats["sample_ns"] > first        # grows with sampled rows
+    assert eng.stats["device_sampled_rows"] == len(calls)
     eng.stats = {k: 0 for k in eng.stats}        # the reset idiom
     assert eng.stats["sample_ns"] == 0
+    assert eng.stats["device_sampled_rows"] == 0
 
 
 # ---------------------------------------------------------------------------

@@ -270,7 +270,8 @@ def test_decode_program_keeps_the_kernel_names_traces_are_read_by(family,
     eng = ContinuousEngine(cfg, params, attn_impl="pallas", **_KW)
     lowered = eng._decode.lower(
         eng.params, jnp.zeros((eng.max_batch, 1), jnp.int32), eng.pool,
-        jnp.asarray(eng.tables), jnp.asarray(eng.lengths))
+        jnp.asarray(eng.tables), jnp.asarray(eng.lengths),
+        *eng._sampler_args(np.zeros((eng.max_batch,), np.float32)))
     assert kernel in lowered.as_text()
 
 
